@@ -149,6 +149,9 @@ def _fused_vs_staged(n: int, out) -> list:
 
 
 def main() -> None:
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=1024, help="problem size for fig3/fig4")
     ap.add_argument("--quick", action="store_true", help="smaller sweeps")

@@ -16,8 +16,10 @@ and the plan/jit lru-cache tallies.
 import numpy as np
 
 import repro.obs as obs
+from repro.compile_cache import use_persistent_cache
 from repro.core import GaussianProcess, Matern52, Scaled, Sum, White
 
+use_persistent_cache()
 obs.enable()
 
 rng = np.random.default_rng(0)

@@ -9,11 +9,13 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_persistent_cache
 from repro.core import GaussianProcess
 from repro.data.msd import MSDConfig, make_dataset
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=2048, help="training samples")
     ap.add_argument("--n-test", type=int, default=512)

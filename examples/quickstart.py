@@ -5,8 +5,10 @@
 
 import numpy as np
 
+from repro.compile_cache import use_persistent_cache
 from repro.core import GaussianProcess, SEKernelParams
 
+use_persistent_cache()
 rng = np.random.default_rng(0)
 x_train = rng.uniform(-3, 3, (256, 1)).astype(np.float32)
 y_train = np.sin(x_train[:, 0]) + 0.1 * rng.standard_normal(256).astype(np.float32)

@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 import repro.obs as obs
+from repro.compile_cache import use_persistent_cache
 from repro.core import GaussianProcess, GPBatch, GPFleet
 from repro.core import predict as pred
 from repro.data.msd import MSDConfig, make_dataset, nfir_features, simulate
@@ -229,6 +230,7 @@ def serve_online(args, cfg):
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=4096)
     ap.add_argument("--tile", type=int, default=512)

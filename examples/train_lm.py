@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import use_persistent_cache
 from repro.data.synthetic import token_batches
 from repro.models import transformer as tf
 from repro.optim import Adam, cosine_warmup
@@ -44,6 +45,7 @@ def build_config(arch: str, size: str):
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list(configs.ARCH_IDS))
     ap.add_argument("--size", default="smoke", choices=["smoke", "100m", "full"])

@@ -1,126 +1,60 @@
-"""Shims over jax public-API drift (0.4.x vs >= 0.5 surfaces).
+"""Stable names over the JAX mesh/sharding surface.
 
-The repo targets the current jax surface (``jax.set_mesh``,
-``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.sharding.get_abstract_mesh``); older pins keep those under
-``jax.experimental`` or lack them entirely.  Every shim degrades to the
-same semantics on the old API:
+Callers import these names instead of the JAX attributes directly, so a
+future change of the JAX surface is absorbed here:
 
-* ``shard_map``       — top-level export, else the experimental home.
-* ``make_mesh``       — all-Auto mesh; old jax has no axis types (every
-                        axis behaves Auto), so the kwarg is simply dropped.
-* ``set_mesh``        — ``jax.set_mesh`` where present; else the mesh is
-                        tracked module-locally for :func:`shard_hint`.
+* ``shard_map``       — ``jax.shard_map``.
+* ``make_mesh``       — an explicit all-Auto mesh.
+* ``set_mesh``        — ``jax.set_mesh``.
 * ``abstract_mesh``   — the ambient mesh's abstract view or None.
-* ``shard_hint``      — with_sharding_constraint against the ambient mesh;
-                        a no-op wherever a constraint is unrepresentable
-                        (no mesh, or manual axes under old-jax shard_map).
+* ``shard_hint``      — with_sharding_constraint against the ambient mesh.
+* ``enable_x64``      — a context manager turning on 64-bit types.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, PartitionSpec
 
-try:  # jax >= 0.5: top-level export
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SM_PARAMS = set(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(*args, **kwargs):
-    """jax.shard_map with the replication-check kwarg name normalized
-    (``check_vma`` on new jax, ``check_rep`` on the experimental API)."""
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(*args, **kwargs)
-
-try:  # jax >= 0.5.1
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-_state = {"mesh": None}
+shard_map = jax.shard_map
 
 
 def make_mesh(shape, axes):
-    """An explicit all-Auto mesh on any jax version."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """An explicit all-Auto mesh."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh) -> None:
     """Install the mesh context used by activation sharding constraints."""
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-    else:
-        _state["mesh"] = mesh
+    jax.set_mesh(mesh)
 
 
 def abstract_mesh():
     """The ambient mesh (abstract view), or None outside any mesh context."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        m = get()
-        return None if m is None or not m.axis_names else m
-    m = _state["mesh"]
-    return None if m is None else m.abstract_mesh
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or not m.axis_names else m
 
 
 def auto_axis_names(mesh) -> set:
-    """Mesh axes eligible for sharding constraints (Auto axes).
-
-    Old jax has no axis types; every axis of a tracked mesh behaves Auto.
-    """
-    types = getattr(mesh, "axis_types", None)
-    if AxisType is None or types is None:
-        return set(mesh.axis_names)
-    return {n for n, t in zip(mesh.axis_names, types) if t == AxisType.Auto}
+    """Mesh axes eligible for sharding constraints (Auto axes)."""
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types) if t == AxisType.Auto}
 
 
 def axis_size(name) -> int:
-    """Static size of a mapped mesh axis (``lax.axis_size`` on new jax).
-
-    Old jax lacks the helper; ``psum`` of the literal 1 constant-folds to the
-    axis size there, staying a static Python int usable in shapes.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return int(jax.lax.psum(1, name))
+    """Static size of a mapped mesh axis."""
+    return jax.lax.axis_size(name)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on any jax version.
-
-    Old jax returns a one-element list of per-program dicts; new jax returns
-    the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return compiled.cost_analysis()
 
 
 def shard_hint(x, spec: PartitionSpec):
-    """``with_sharding_constraint(x, spec)`` against the ambient mesh.
+    """``with_sharding_constraint(x, spec)`` against the ambient mesh."""
+    return jax.lax.with_sharding_constraint(x, spec)
 
-    On new jax the bare PartitionSpec binds to the set_mesh context.  On old
-    jax the constraint needs the concrete tracked mesh; inside shard_map
-    (manual axes) such a constraint is unrepresentable and the hint must be
-    a no-op, which surfaces as a trace-time error we swallow.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.lax.with_sharding_constraint(x, spec)
-    m = _state["mesh"]
-    if m is None:
-        return x
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(m, spec))
-    except Exception:
-        return x
+
+def enable_x64():
+    """Context manager enabling float64/int64 inside its block."""
+    return jax.enable_x64(True)

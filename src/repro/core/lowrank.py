@@ -50,7 +50,7 @@ import numpy as np
 from repro.core import executor
 from repro.core import kernels_math as km
 from repro.core import predict as pred
-from repro.core import tiling, triangular
+from repro.core import precision, tiling, triangular
 
 # K_uu is regularized with a small jitter (NOT the noise variance) so the
 # approximation converges to the exact GP as m -> n.  1e-4 is the float32
@@ -328,6 +328,7 @@ def _build_fn(cfg):
             c_chunks=c, gamma=gamma, yty=yty,
         )
 
+    build = precision.f32_matmuls(build)
     if backend == "jnp":
         return jax.jit(build)
     return build
@@ -417,6 +418,7 @@ def lowrank_state(
 # ---------------------------------------------------------------------------
 
 
+@precision.f32_matmuls
 def absorb(
     state: LowRankState,
     x_new: jax.Array,
@@ -554,6 +556,7 @@ def _head_fn(cfg):
         cov = jnp.where(eye, jnp.clip(cov, 0.0, None), cov)
         return mean, cov
 
+    head = precision.f32_matmuls(head)
     if backend == "jnp":
         return jax.jit(head)
     return head

@@ -48,9 +48,10 @@ import jax.numpy as jnp
 
 from repro.core import cholesky as chol
 from repro.core import kernels_math as km
-from repro.core import tiling, triangular
+from repro.core import precision, tiling, triangular
 
 
+@precision.f32_matmuls
 def negative_log_marginal_likelihood(
     x: jax.Array,
     y: jax.Array,
@@ -205,6 +206,7 @@ def _nlml_dense_grads(kernel, params, xd, alpha, kinv):
     return g_xa + g_xb, alpha, g_params
 
 
+@precision.f32_matmuls
 def _nlml_cv_bwd(cfg, res, ct):
     # analytic-vjp kernels only (SE, Matérn 5/2): nlml_tiled routes every
     # other family to vjp="autodiff" before this rule can be installed.
@@ -255,6 +257,7 @@ def _nlml_batched_cv_fwd(cfg, x, y, params):
     return val, (x, y, params, lpacked, alpha_c)
 
 
+@precision.f32_matmuls
 def _nlml_batched_cv_bwd(cfg, res, ct):
     _, n_streams, _, _, dtype_name, _, kernel = cfg
     dtype = jnp.dtype(dtype_name)
@@ -436,6 +439,7 @@ def _nlml_lr_fwd(cfg, x, y, u, params):
     return val, (x, y, u, params, state.luu_packed, state.lb_packed, state.gamma)
 
 
+@precision.f32_matmuls
 def _nlml_lr_bwd(cfg, res, ct):
     mu, _, _, _, _, _, dtype_name, kernel = cfg
     dtype = jnp.dtype(dtype_name)
@@ -761,7 +765,7 @@ def _adam_scan_impl(vg, steps: int, lr: float):
         (raw, _, _), losses = jax.lax.scan(step, (raw0, z, z), ts)
         return raw, losses
 
-    return jax.jit(run)
+    return jax.jit(precision.f32_matmuls(run))
 
 
 def adam_scan(loss, steps: int, lr: float):

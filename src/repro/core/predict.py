@@ -41,7 +41,7 @@ import repro.obs as obs
 from repro.core import cholesky as chol
 from repro.core import executor
 from repro.core import kernels_math as km
-from repro.core import tiling, triangular
+from repro.core import precision, tiling, triangular
 from repro.dist import sharding as dist_sharding
 
 # Dispatch-boundary trace spans (DESIGN.md §15).  The jnp fast paths run
@@ -283,6 +283,7 @@ class PosteriorState:
         return upd.shrink_state(self, k, **kwargs)
 
 
+@precision.f32_matmuls
 def posterior_state(
     x_train: jax.Array,
     y_train: jax.Array,
@@ -313,6 +314,7 @@ def posterior_state(
     )
 
 
+@precision.f32_matmuls
 def predict_from_state(
     state: PosteriorState,
     x_test: jax.Array,
@@ -416,6 +418,7 @@ def _fused_program_fn(
                 kernel=kernel,
             )
 
+        ragged_fn = precision.f32_matmuls(ragged_fn)
         return jax.jit(ragged_fn) if backend == "jnp" else ragged_fn
 
     def fn(xc, yc, xtc, params):
@@ -435,6 +438,7 @@ def _fused_program_fn(
             kernel=kernel,
         )
 
+    fn = precision.f32_matmuls(fn)
     return jax.jit(fn) if backend == "jnp" else fn
 
 
@@ -588,6 +592,7 @@ def predict_fused_batched(
     return result, state
 
 
+@precision.f32_matmuls
 def predict_from_state_batched(
     state: PosteriorState,
     x_test: jax.Array,
@@ -787,6 +792,7 @@ def predict_staged(
     )
 
 
+@precision.f32_matmuls
 def predict_monolithic(
     x_train: jax.Array,
     y_train: jax.Array,

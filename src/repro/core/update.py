@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.obs as obs
-from repro.core import executor, tiling, triangular
+from repro.core import executor, precision, tiling, triangular
 
 
 def _record_step(kind: str, plan, backend: str, batched: bool, operand) -> None:
@@ -144,6 +144,7 @@ def _append_step_fn(
             beta = beta.at[off + (r_tiles,)].set(beta_new)
         return lpacked, xc, yc, beta
 
+    fn = precision.f32_matmuls(fn)
     return jax.jit(fn) if backend == "jnp" else fn
 
 
@@ -171,6 +172,7 @@ def _evict_step_fn(
         )
         return new_packed
 
+    fn = precision.f32_matmuls(fn)
     return jax.jit(fn) if backend == "jnp" else fn
 
 
@@ -190,7 +192,7 @@ def _resolve_fn(n_streams: Optional[int], forward: bool):
         alpha = triangular.backward_substitution(lpacked, beta, n_streams=n_streams)
         return beta, alpha
 
-    return jax.jit(fn)
+    return jax.jit(precision.f32_matmuls(fn))
 
 
 def _check(state_arrays, what: str) -> None:

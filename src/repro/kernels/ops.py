@@ -5,9 +5,11 @@ Exposes the same per-tile signatures as the jnp backend in
 the batched entry points and the covariance-assembly routines used by
 ``repro.core.predict``.
 
-``interpret=True`` is selected automatically off-TPU: the kernel bodies
-execute in Python on CPU, which is how this container validates them; on a
-real TPU the same `pallas_call`s lower through Mosaic.
+The execution mode follows the default backend: on ``"tpu"`` the
+`pallas_call`s lower through Mosaic; on ``"cpu"`` they run in interpret mode
+(the kernel bodies execute as jnp on the host), which is how the test suite
+validates them.  Any other backend raises rather than silently running the
+interpreter in place of the kernels.
 
 Differentiability (DESIGN.md §8): the per-tile ops carry ``jax.custom_vjp``
 hooks whose backward passes differentiate the *jnp reference* implementation
@@ -54,7 +56,15 @@ from repro.kernels import trsm_tile as _trsm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels lower for 'tpu' and run interpreted on 'cpu'; "
+        f"the default backend is {backend!r}"
+    )
 
 
 # ---------------------------------------------------------------------------

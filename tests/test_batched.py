@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import GaussianProcess, GPBatch, SEKernelParams
 from repro.core import executor, mll, tiling
 from repro.core import predict as pred
@@ -247,8 +248,7 @@ def test_run_cholesky_batched_matches_loop(rng, spd):
 def test_dtype_flows_float64(rng):
     """The dtype knob reaches padding + assembly end-to-end (no implicit
     float32): float64 GPs stay float64 through predict and nlml."""
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64():
+    with compat.enable_x64():
         n, d = 40, 2
         x = rng.standard_normal((n, d))
         y = rng.standard_normal(n)

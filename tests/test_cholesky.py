@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import cholesky as chol
 from repro.core import tiling
 
@@ -61,8 +62,7 @@ def test_pallas_backend_matches(rng):
 def test_float64(rng):
     # f64 path (CPU validation dtype; TPU runs f32/bf16 — DESIGN.md §2)
     k = _spd(rng, 64, np.float64)
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64():
+    with compat.enable_x64():
         l_t = np.asarray(chol.cholesky_dense_via_tiles(jnp.asarray(k), 16))
         np.testing.assert_allclose(l_t, np.linalg.cholesky(k), atol=1e-10)
 

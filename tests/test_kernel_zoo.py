@@ -16,15 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import executor
 from repro.core import kernels_math as km
 from repro.core import mll
 from repro.core import predict as pred
 from repro.core.gp import GaussianProcess, GPFleet
-
-
-def _x64():
-    return getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
 
 
 # one cell per registered family, plus composite instances that exercise
@@ -100,7 +97,7 @@ def test_zoo_equivalence_grid(name, kern, n, m, backend):
 )
 def test_zoo_autodiff_vjp_matches_finite_differences(name, kern):
     """The autodiff NLML gradient (the non-SE fallback) against f64 FD."""
-    with _x64()():
+    with compat.enable_x64():
         x, y, _ = _data(48)
         x64 = jnp.asarray(x, jnp.float64)
         y64 = jnp.asarray(y, jnp.float64)

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core.kernels_math import SEKernelParams
 from repro.kernels import ops, ref
 from repro.kernels.cov_assembly import cov_tiles
@@ -33,8 +34,7 @@ def test_potrf_shapes(rng, m):
 
 
 def test_potrf_f64(rng):
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64():
+    with compat.enable_x64():
         k = _spd(rng, 32, np.float64)
         out = np.asarray(ops.potrf(jnp.asarray(k)))
         np.testing.assert_allclose(out, np.linalg.cholesky(k), atol=1e-10)

@@ -16,16 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import GaussianProcess, GPBatch, GPFleet
 from repro.core import executor, lowrank, mll
 from repro.core.kernels_math import SEKernelParams
 
 M = 16
 PARAMS = SEKernelParams(lengthscale=0.7, vertical=1.2, noise=0.05)
-
-
-def _x64():
-    return getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
 
 
 def _data(rng, n, d=2, nt=7):
@@ -194,7 +191,7 @@ def test_sliding_window_evicts_exact_count(rng):
 def test_gpbatch_matches_per_problem_loop_f64(rng):
     """float64 pins the loop equivalence to 1e-5 (f32 einsum-order roundoff
     would dominate otherwise); also: growing B adds ZERO Plan-cache misses."""
-    with _x64()():
+    with compat.enable_x64():
         B, n, mi = 3, 64, 32
         x = rng.standard_normal((B, n, 2))
         y = rng.standard_normal((B, n))

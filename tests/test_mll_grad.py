@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import kernels_math as km
 from repro.core import mll, tiling
 from repro.core import predict as pred
@@ -37,12 +38,8 @@ def _tols(backend, dt):
     return _VALUE_RTOL[eff], _GRAD_RTOL[eff]
 
 
-def _x64():
-    return getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-
-
 def _ctx(dt):
-    return _x64()() if dt == "float64" else contextlib.nullcontext()
+    return compat.enable_x64() if dt == "float64" else contextlib.nullcontext()
 
 
 def _data(n, dt):
@@ -122,7 +119,7 @@ def test_nlml_tiled_grad_matches_finite_differences(n, backend):
     The jnp backend is f64 end-to-end, so a tiny step resolves the gradient
     to ~1e-9; the Pallas forward rounds internally through f32, so its step
     must be large enough for the secant to dominate that rounding noise."""
-    with _x64()():
+    with compat.enable_x64():
         dtype = jnp.float64
         x, y = _data(n, "float64")
         params = _params(dtype)
@@ -147,7 +144,7 @@ def test_matern52_analytic_vjp_matches_finite_differences(method):
     """The hand-derived Matérn-5/2 kfree VJP, contracted by both blocked
     custom rules (exact tier and Woodbury low-rank tier), against central
     finite differences in float64."""
-    with _x64()():
+    with compat.enable_x64():
         dtype = jnp.float64
         n = 48
         x, y = _data(n, "float64")
@@ -228,7 +225,7 @@ def test_nlml_tiled_grads_wrt_inputs_match_monolithic():
 
 def test_pack_preserves_float64():
     """Regression: _pack hard-coded float32, silently rounding f64 params."""
-    with _x64()():
+    with compat.enable_x64():
         p = SEKernelParams(
             jnp.asarray(1.5, jnp.float64),
             jnp.asarray(2.0, jnp.float64),

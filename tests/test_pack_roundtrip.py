@@ -17,12 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import kernels_math as km
 from repro.core import mll
-
-
-def _x64():
-    return getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
 
 
 # endpoints, the old overflow knee (~90), the branch point (20), and a
@@ -43,7 +40,7 @@ def test_roundtrip_float32():
 
 
 def test_roundtrip_float64():
-    with _x64()():
+    with compat.enable_x64():
         v = jnp.asarray(_values(), jnp.float64)
         back = mll.unpack_params(mll.pack_params(v))
         assert back.dtype == jnp.float64

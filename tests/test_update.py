@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import GaussianProcess, GPBatch, SEKernelParams
 from repro.core import executor, scheduler, tiling, triangular, update
 from repro.core import predict as pred
@@ -125,8 +126,7 @@ def test_extend_matches_scratch(rng, n0, b, backend):
 
 def test_extend_float64_exactish(rng):
     """The f64 guardrail path: append error at the 1e-12 level."""
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64():
+    with compat.enable_x64():
         n0, b, m = 40, 13, 16
         x, y = _data(rng, n0 + b, dtype=np.float64)
         state = pred.posterior_state(
