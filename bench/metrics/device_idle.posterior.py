@@ -1,0 +1,3 @@
+"""Share of the traced window in which no op ran on the device, in %."""
+
+from bench.readers import device_idle as read  # noqa: F401
