@@ -54,6 +54,12 @@ from repro.core import scheduler as sch
 from repro.core import tiling
 from repro.dist import sharding as dist_sharding
 
+# Each batch of a plan runs inside ``jax.named_scope("repro.exec.<op>")``:
+# every device op of the compiled program then carries its op family in its
+# metadata (HLO ``op_name``, the profiler trace's ``tf_op``).  Metadata only;
+# the compiled program is unchanged (DESIGN.md §15).
+_SCOPE = obs.Tracer("repro.exec")
+
 
 # ---------------------------------------------------------------------------
 # Tile-level ops (jnp backend).  a/b are (m, m) tiles; batched via vmap.
@@ -420,22 +426,23 @@ def run_cholesky(
     )
     for level in plan.levels:
         for bt in level:
-            if bt.op == sch.POTRF:
-                packed = put(packed, bt.out, potrf_b(take(packed, bt.a)))
-            elif bt.op == sch.TRSM:
-                packed = put(
-                    packed, bt.out, trsm_b(take(packed, bt.a), take(packed, bt.b))
-                )
-            elif bt.op == sch.SYRK:
-                packed = put(
-                    packed, bt.out, syrk_b(take(packed, bt.a), take(packed, bt.b))
-                )
-            else:
-                packed = put(
-                    packed,
-                    bt.out,
-                    gemm_b(take(packed, bt.a), take(packed, bt.b), take(packed, bt.c)),
-                )
+            with _SCOPE.named_scope(bt.op):
+                if bt.op == sch.POTRF:
+                    packed = put(packed, bt.out, potrf_b(take(packed, bt.a)))
+                elif bt.op == sch.TRSM:
+                    packed = put(
+                        packed, bt.out, trsm_b(take(packed, bt.a), take(packed, bt.b))
+                    )
+                elif bt.op == sch.SYRK:
+                    packed = put(
+                        packed, bt.out, syrk_b(take(packed, bt.a), take(packed, bt.b))
+                    )
+                else:
+                    packed = put(
+                        packed,
+                        bt.out,
+                        gemm_b(take(packed, bt.a), take(packed, bt.b), take(packed, bt.c)),
+                    )
     return packed
 
 
@@ -635,7 +642,8 @@ def run_lowrank_contraction(
     out = jnp.zeros(kun.shape[:-4] + (mu_tiles, kun.shape[-2]), kun.dtype)
     for level in plan.levels:
         for bt in level:
-            out = add(out, bt.out, mv(take(kflat, bt.a), take(yc, bt.b)))
+            with _SCOPE.named_scope(bt.op):
+                out = add(out, bt.out, mv(take(kflat, bt.a), take(yc, bt.b)))
     return out
 
 
@@ -899,76 +907,77 @@ def run_program(
 
     for level in plan.levels:
         for bt in level:
-            op, packed = bt.op, env["packed"]
-            if op == sch.ASSEMBLE:
-                tiles = asm(take(xc, bt.a), take(xc, bt.b), off(bt.a), off(bt.b))
-                env["packed"] = put(packed, bt.out, tiles)
-            elif op == sch.CROSS:
-                tiles = crossf(take(xtc, bt.a), take(xc, bt.b), off(bt.a), off(bt.b))
-                env["cross"] = put(env["cross"], bt.out, tiles)
-            elif op == sch.PRIOR:
-                tiles = priorf(take(xtc, bt.a), take(xtc, bt.b), off(bt.a), off(bt.b))
-                env["prior"] = put(env["prior"], bt.out, tiles)
-            elif op == sch.POTRF:
-                env["packed"] = put(packed, bt.out, potrf_b(take(packed, bt.a)))
-            elif op == sch.TRSM:
-                env["packed"] = put(
-                    packed, bt.out, trsm_b(take(packed, bt.a), take(packed, bt.b))
-                )
-            elif op == TRAIL:
-                env["packed"] = put(
-                    packed,
-                    bt.out,
-                    trail_b(take(packed, bt.a), take(packed, bt.b), take(packed, bt.c)),
-                )
-            elif op == sch.TRSV:
-                sol = _trsv_batch(take(packed, bt.a), take(env["y"], bt.out), False)
-                env["y"] = put(env["y"], bt.out, sol)
-                # publish the solved row into the backward pass's buffer
-                env["alpha"] = put(env["alpha"], bt.out, sol)
-            elif op == sch.GEMV:
-                upd = jnp.einsum(
-                    f"{Z}gab,{Z}gb->{Z}ga", take(packed, bt.a), take(env["y"], bt.b)
-                )
-                env["y"] = add(env["y"], bt.out, -upd.astype(dtype))
-            elif op == sch.TRSV_B:
-                sol = _trsv_batch(take(packed, bt.a), take(env["alpha"], bt.out), True)
-                env["alpha"] = put(env["alpha"], bt.out, sol)
-            elif op == sch.GEMV_B:
-                upd = jnp.einsum(
-                    f"{Z}gba,{Z}gb->{Z}ga", take(packed, bt.a), take(env["alpha"], bt.b)
-                )
-                env["alpha"] = add(env["alpha"], bt.out, -upd.astype(dtype))
-            elif op == sch.XGEMV:
-                rows = take(cross_grid(), bt.out)
-                env["mean"] = put(
-                    env["mean"],
-                    bt.out,
-                    jnp.einsum(f"{Z}gqab,{Z}qb->{Z}ga", rows, env["alpha"]),
-                )
-            elif op == sch.VINIT:
-                if batched:
-                    cols = cross_grid()[:, :, bt.out]      # (B, Q, G, m, m)
-                    vrows = cols.transpose(0, 2, 1, 4, 3)  # (B, G, Q, m, m)
+            with _SCOPE.named_scope(bt.op):
+                op, packed = bt.op, env["packed"]
+                if op == sch.ASSEMBLE:
+                    tiles = asm(take(xc, bt.a), take(xc, bt.b), off(bt.a), off(bt.b))
+                    env["packed"] = put(packed, bt.out, tiles)
+                elif op == sch.CROSS:
+                    tiles = crossf(take(xtc, bt.a), take(xc, bt.b), off(bt.a), off(bt.b))
+                    env["cross"] = put(env["cross"], bt.out, tiles)
+                elif op == sch.PRIOR:
+                    tiles = priorf(take(xtc, bt.a), take(xtc, bt.b), off(bt.a), off(bt.b))
+                    env["prior"] = put(env["prior"], bt.out, tiles)
+                elif op == sch.POTRF:
+                    env["packed"] = put(packed, bt.out, potrf_b(take(packed, bt.a)))
+                elif op == sch.TRSM:
+                    env["packed"] = put(
+                        packed, bt.out, trsm_b(take(packed, bt.a), take(packed, bt.b))
+                    )
+                elif op == TRAIL:
+                    env["packed"] = put(
+                        packed,
+                        bt.out,
+                        trail_b(take(packed, bt.a), take(packed, bt.b), take(packed, bt.c)),
+                    )
+                elif op == sch.TRSV:
+                    sol = _trsv_batch(take(packed, bt.a), take(env["y"], bt.out), False)
+                    env["y"] = put(env["y"], bt.out, sol)
+                    # publish the solved row into the backward pass's buffer
+                    env["alpha"] = put(env["alpha"], bt.out, sol)
+                elif op == sch.GEMV:
+                    upd = jnp.einsum(
+                        f"{Z}gab,{Z}gb->{Z}ga", take(packed, bt.a), take(env["y"], bt.b)
+                    )
+                    env["y"] = add(env["y"], bt.out, -upd.astype(dtype))
+                elif op == sch.TRSV_B:
+                    sol = _trsv_batch(take(packed, bt.a), take(env["alpha"], bt.out), True)
+                    env["alpha"] = put(env["alpha"], bt.out, sol)
+                elif op == sch.GEMV_B:
+                    upd = jnp.einsum(
+                        f"{Z}gba,{Z}gb->{Z}ga", take(packed, bt.a), take(env["alpha"], bt.b)
+                    )
+                    env["alpha"] = add(env["alpha"], bt.out, -upd.astype(dtype))
+                elif op == sch.XGEMV:
+                    rows = take(cross_grid(), bt.out)
+                    env["mean"] = put(
+                        env["mean"],
+                        bt.out,
+                        jnp.einsum(f"{Z}gqab,{Z}qb->{Z}ga", rows, env["alpha"]),
+                    )
+                elif op == sch.VINIT:
+                    if batched:
+                        cols = cross_grid()[:, :, bt.out]      # (B, Q, G, m, m)
+                        vrows = cols.transpose(0, 2, 1, 4, 3)  # (B, G, Q, m, m)
+                    else:
+                        cols = cross_grid()[:, bt.out]         # (Q, G, m, m)
+                        vrows = cols.transpose(1, 0, 3, 2)     # (G, Q, m, m)
+                    env["v"] = put(env["v"], bt.out, vrows)
+                elif op == sch.VTRSV:
+                    sol = _trsv_batch(take(packed, bt.a), take(env["v"], bt.out), False)
+                    env["v"] = put(env["v"], bt.out, sol)
+                elif op == sch.VGEMV:
+                    upd = jnp.einsum(
+                        f"{Z}gab,{Z}gqbc->{Z}gqac", take(packed, bt.a), take(env["v"], bt.b)
+                    )
+                    env["v"] = add(env["v"], bt.out, -upd.astype(dtype))
+                elif op == sch.GRAM:
+                    w = jnp.einsum(f"{Z}ipab,{Z}iqac->{Z}pqbc", env["v"], env["v"])
+                    env["prior"] = env["prior"] - w.reshape(
+                        lead + (q_tiles * q_tiles, m, m)
+                    )
                 else:
-                    cols = cross_grid()[:, bt.out]         # (Q, G, m, m)
-                    vrows = cols.transpose(1, 0, 3, 2)     # (G, Q, m, m)
-                env["v"] = put(env["v"], bt.out, vrows)
-            elif op == sch.VTRSV:
-                sol = _trsv_batch(take(packed, bt.a), take(env["v"], bt.out), False)
-                env["v"] = put(env["v"], bt.out, sol)
-            elif op == sch.VGEMV:
-                upd = jnp.einsum(
-                    f"{Z}gab,{Z}gqbc->{Z}gqac", take(packed, bt.a), take(env["v"], bt.b)
-                )
-                env["v"] = add(env["v"], bt.out, -upd.astype(dtype))
-            elif op == sch.GRAM:
-                w = jnp.einsum(f"{Z}ipab,{Z}iqac->{Z}pqbc", env["v"], env["v"])
-                env["prior"] = env["prior"] - w.reshape(
-                    lead + (q_tiles * q_tiles, m, m)
-                )
-            else:
-                raise ValueError(op)
+                    raise ValueError(op)
     return env
 
 
@@ -1007,12 +1016,13 @@ def run_solve(
         ein = f"{Z}gba,{Z}gb->{Z}ga" if transpose else f"{Z}gab,{Z}gb->{Z}ga"
     for level in plan.levels:
         for bt in level:
-            if bt.op == sch.TRSV:
-                sol = _trsv_batch(take(lpacked, bt.a), take(rhs, bt.out), transpose)
-                rhs = put(rhs, bt.out, sol)
-            else:
-                upd = jnp.einsum(ein, take(lpacked, bt.a), take(rhs, bt.b))
-                rhs = add(rhs, bt.out, -upd.astype(rhs.dtype))
+            with _SCOPE.named_scope(bt.op):
+                if bt.op == sch.TRSV:
+                    sol = _trsv_batch(take(lpacked, bt.a), take(rhs, bt.out), transpose)
+                    rhs = put(rhs, bt.out, sol)
+                else:
+                    upd = jnp.einsum(ein, take(lpacked, bt.a), take(rhs, bt.b))
+                    rhs = add(rhs, bt.out, -upd.astype(rhs.dtype))
     return rhs
 
 
@@ -1177,37 +1187,38 @@ def run_append(
 
     for level in plan.levels:
         for bt in level:
-            if bt.op == sch.UASM:
-                tiles = crossf(
-                    bcast_row(bt.size), take(xc, bt.a),
-                    jnp.full((bt.size,), row0, jnp.int32), off(bt.a),
-                )
-                row = put(row, bt.out, tiles)
-            elif bt.op == sch.UASMD:
-                tiles = diagf(
-                    bcast_row(1), bcast_row(1),
-                    jnp.full((1,), row0, jnp.int32),
-                    jnp.full((1,), row0, jnp.int32),
-                )
-                row = put(row, bt.out, tiles)
-            elif bt.op == sch.UTRSM:
-                row = put(
-                    row, bt.out, trsm_b(take(lpacked, bt.a), take(row, bt.b))
-                )
-            elif bt.op == sch.UGEMM:
-                row = put(
-                    row,
-                    bt.out,
-                    gemm_b(take(row, bt.a), take(row, bt.b), take(lpacked, bt.c)),
-                )
-            elif bt.op == sch.USYRK:
-                row = put(
-                    row, bt.out, syrk_b(take(row, bt.a), take(row, bt.b))
-                )
-            elif bt.op == sch.UPOTRF:
-                row = put(row, bt.out, potrf_b(take(row, bt.a)))
-            else:
-                raise ValueError(bt.op)
+            with _SCOPE.named_scope(bt.op):
+                if bt.op == sch.UASM:
+                    tiles = crossf(
+                        bcast_row(bt.size), take(xc, bt.a),
+                        jnp.full((bt.size,), row0, jnp.int32), off(bt.a),
+                    )
+                    row = put(row, bt.out, tiles)
+                elif bt.op == sch.UASMD:
+                    tiles = diagf(
+                        bcast_row(1), bcast_row(1),
+                        jnp.full((1,), row0, jnp.int32),
+                        jnp.full((1,), row0, jnp.int32),
+                    )
+                    row = put(row, bt.out, tiles)
+                elif bt.op == sch.UTRSM:
+                    row = put(
+                        row, bt.out, trsm_b(take(lpacked, bt.a), take(row, bt.b))
+                    )
+                elif bt.op == sch.UGEMM:
+                    row = put(
+                        row,
+                        bt.out,
+                        gemm_b(take(row, bt.a), take(row, bt.b), take(lpacked, bt.c)),
+                    )
+                elif bt.op == sch.USYRK:
+                    row = put(
+                        row, bt.out, syrk_b(take(row, bt.a), take(row, bt.b))
+                    )
+                elif bt.op == sch.UPOTRF:
+                    row = put(row, bt.out, potrf_b(take(row, bt.a)))
+                else:
+                    raise ValueError(bt.op)
     return row
 
 
@@ -1334,32 +1345,33 @@ def run_rank_update(
     caux = shard(jnp.zeros_like(xaux))
     for level in plan.levels:
         for bt in level:
-            if bt.op == sch.UPREP:
-                lnew, x, y, c = uprep_b(take(lpacked, bt.a), take(w, bt.out))
-                lpacked = put(lpacked, bt.a, lnew)
-                xaux = put(xaux, bt.out, x)
-                yaux = put(yaux, bt.out, y)
-                caux = put(caux, bt.out, c)
-            elif bt.op == sch.UPROW:
-                lpacked = put(
-                    lpacked,
-                    bt.out,
-                    uprow_b(
-                        take(lpacked, bt.a), take(w, bt.b),
-                        take(xaux, bt.c), take(yaux, bt.c),
-                    ),
-                )
-            elif bt.op == sch.UCARRY:
-                w = put(
-                    w,
-                    bt.out,
-                    ucarry_b(
-                        take(w, bt.b), take(lpacked, bt.a),
-                        take(yaux, bt.c), take(caux, bt.c),
-                    ).astype(w.dtype),
-                )
-            else:
-                raise ValueError(bt.op)
+            with _SCOPE.named_scope(bt.op):
+                if bt.op == sch.UPREP:
+                    lnew, x, y, c = uprep_b(take(lpacked, bt.a), take(w, bt.out))
+                    lpacked = put(lpacked, bt.a, lnew)
+                    xaux = put(xaux, bt.out, x)
+                    yaux = put(yaux, bt.out, y)
+                    caux = put(caux, bt.out, c)
+                elif bt.op == sch.UPROW:
+                    lpacked = put(
+                        lpacked,
+                        bt.out,
+                        uprow_b(
+                            take(lpacked, bt.a), take(w, bt.b),
+                            take(xaux, bt.c), take(yaux, bt.c),
+                        ),
+                    )
+                elif bt.op == sch.UCARRY:
+                    w = put(
+                        w,
+                        bt.out,
+                        ucarry_b(
+                            take(w, bt.b), take(lpacked, bt.a),
+                            take(yaux, bt.c), take(caux, bt.c),
+                        ).astype(w.dtype),
+                    )
+                else:
+                    raise ValueError(bt.op)
     return lpacked, w
 
 

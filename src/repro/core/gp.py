@@ -43,6 +43,10 @@ from repro.core import lowrank
 from repro.core import predict as pred
 from repro.core import tiling
 
+# Host spans of the predict calls (DESIGN.md §15): one ``repro.gp.predict``
+# per call, around the cache lookup, the program's pad/launch/untile spans
+# (``repro.predict.*``) and the variance diagonal.
+_tracer = obs.Tracer("repro.gp")
 
 def _lowrank_state_with_retry(build, base_jitter: float) -> lowrank.LowRankState:
     """Cold Nyström build with escalating-jitter retries (DESIGN.md §15).
@@ -398,8 +402,10 @@ class GaussianProcess:
         """Route a tiled prediction: cached factor -> staged tail stages;
         cold + ``fused`` -> one whole-pipeline program whose buffer env also
         populates the posterior cache; cold staged -> posterior() then tail."""
-        key = self._cache_key()
-        if self._posterior is not None and self._posterior_key == key:
+        with _tracer.span("lookup"):
+            key = self._cache_key()
+            warm = self._posterior is not None and self._posterior_key == key
+        if warm:
             obs.inc("cache.posterior.warm")
             state = self._posterior
         elif self.fused:
@@ -441,37 +447,31 @@ class GaussianProcess:
             dtype=self.dtype,
         )
 
-    def predict(self, x_test: jax.Array) -> jax.Array:
+    def _predict(self, x_test: jax.Array, full_cov: bool):
         x_test = self._prep(x_test)
         if self.method == "lowrank":
-            return self._predict_lowrank(x_test, full_cov=False)
+            return self._predict_lowrank(x_test, full_cov)
         if self.pipeline == "monolithic":
             return pred.predict_monolithic(
                 self.x_train, self.y_train, x_test, self.params,
-                dtype=self.dtype, kernel=self.kernel,
+                full_cov=full_cov, dtype=self.dtype, kernel=self.kernel,
             )
-        return self._predict_tiled(x_test, full_cov=False)
+        return self._predict_tiled(x_test, full_cov)
+
+    def predict(self, x_test: jax.Array) -> jax.Array:
+        with _tracer.span("predict"):
+            return self._predict(x_test, full_cov=False)
 
     def predict_full_cov(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """The paper's *Predict with Full Covariance Matrix* operation."""
-        x_test = self._prep(x_test)
-        if self.method == "lowrank":
-            return self._predict_lowrank(x_test, full_cov=True)
-        if self.pipeline == "monolithic":
-            return pred.predict_monolithic(
-                self.x_train,
-                self.y_train,
-                x_test,
-                self.params,
-                full_cov=True,
-                dtype=self.dtype,
-                kernel=self.kernel,
-            )
-        return self._predict_tiled(x_test, full_cov=True)
+        with _tracer.span("predict"):
+            return self._predict(x_test, full_cov=True)
 
     def predict_with_uncertainty(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        mean, sigma = self.predict_full_cov(x_test)
-        return mean, jnp.diagonal(sigma)
+        with _tracer.span("predict"):
+            mean, sigma = self._predict(x_test, full_cov=True)
+            with _tracer.span("diag"):
+                return mean, jnp.diagonal(sigma)
 
     # -- hyperparameters ----------------------------------------------------
 
@@ -908,8 +908,10 @@ class GPBatch:
                 dtype=self.dtype,
                 batch_dispatch=self.batch_dispatch,
             )
-        key = self._cache_key()
-        if self._posterior is not None and self._posterior_key == key:
+        with _tracer.span("lookup"):
+            key = self._cache_key()
+            warm = self._posterior is not None and self._posterior_key == key
+        if warm:
             obs.inc("cache.posterior.warm")
             return pred.predict_from_state_batched(
                 self._posterior,
@@ -943,15 +945,19 @@ class GPBatch:
         """Predictive means (B, n̂) for stacked test points (B, n̂, D).
 
         A shared (n̂, D) test block is broadcast to every problem."""
-        return self._predict_batched(self._prep(x_test), full_cov=False)
+        with _tracer.span("predict"):
+            return self._predict_batched(self._prep(x_test), full_cov=False)
 
     def predict_full_cov(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Means (B, n̂) and posterior covariances (B, n̂, n̂)."""
-        return self._predict_batched(self._prep(x_test), full_cov=True)
+        with _tracer.span("predict"):
+            return self._predict_batched(self._prep(x_test), full_cov=True)
 
     def predict_with_uncertainty(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        mean, sigma = self.predict_full_cov(x_test)
-        return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
+        with _tracer.span("predict"):
+            mean, sigma = self._predict_batched(self._prep(x_test), full_cov=True)
+            with _tracer.span("diag"):
+                return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
 
     # -- hyperparameters ----------------------------------------------------
 
@@ -1196,14 +1202,17 @@ class GPFleet:
 
     def _bucket_state(self, cap_tiles, idx):
         """Warm cached stacked state for one bucket, (re)built cold on miss."""
-        key = self._cache_key()
-        rec = self._buckets.get(cap_tiles)
-        if rec is not None and rec.key == key and rec.idx == tuple(idx) \
-                and rec.state is not None:
+        with _tracer.span("bucket"):
+            key = self._cache_key()
+            rec = self._buckets.get(cap_tiles)
+            warm = rec is not None and rec.key == key and rec.idx == tuple(idx) \
+                and rec.state is not None
+        if warm:
             obs.inc("cache.bucket.warm")
             return rec.state
         obs.inc("cache.bucket.cold")
-        xs, ys, nv = self._stack(idx, cap_tiles)
+        with _tracer.span("stack"):
+            xs, ys, nv = self._stack(idx, cap_tiles)
         bp = self._bucket_params(idx)
         if self.method == "lowrank":
             ind = self.inducing
@@ -1269,7 +1278,8 @@ class GPFleet:
         sigma = jnp.zeros((b, nh, nh), self.dtype) if full_cov else None
         for cap, idx in self.bucket_assignment().items():
             state = self._bucket_state(cap, idx)
-            xt = jnp.broadcast_to(x_test[None], (len(idx),) + x_test.shape)
+            with _tracer.span("stack"):
+                xt = jnp.broadcast_to(x_test[None], (len(idx),) + x_test.shape)
             if self.method == "lowrank":
                 out = lowrank.predict_from_lowrank_state(
                     state, xt, full_cov=full_cov, n_streams=self.n_streams,
@@ -1291,14 +1301,18 @@ class GPFleet:
 
     def predict(self, x_test) -> jax.Array:
         """Means (B, n̂) for one shared (n̂, D) test block."""
-        return self._predict_shared(x_test, full_cov=False)
+        with _tracer.span("predict"):
+            return self._predict_shared(x_test, full_cov=False)
 
     def predict_full_cov(self, x_test) -> Tuple[jax.Array, jax.Array]:
-        return self._predict_shared(x_test, full_cov=True)
+        with _tracer.span("predict"):
+            return self._predict_shared(x_test, full_cov=True)
 
     def predict_with_uncertainty(self, x_test) -> Tuple[jax.Array, jax.Array]:
-        mean, sigma = self.predict_full_cov(x_test)
-        return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
+        with _tracer.span("predict"):
+            mean, sigma = self._predict_shared(x_test, full_cov=True)
+            with _tracer.span("diag"):
+                return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
 
     def predict_each(self, x_test_list, *, full_cov: bool = False):
         """Per-problem test sets (list of (n̂_i, D)); ragged n̂_i are padded
@@ -1307,6 +1321,10 @@ class GPFleet:
 
         Returns a length-B list of (n̂_i,) means (or ``(mean, cov)`` tuples
         with cov (n̂_i, n̂_i) when ``full_cov``)."""
+        with _tracer.span("predict"):
+            return self._predict_each(x_test_list, full_cov)
+
+    def _predict_each(self, x_test_list, full_cov: bool):
         b = self.batch_size
         if len(x_test_list) != b:
             raise ValueError(
@@ -1335,10 +1353,11 @@ class GPFleet:
                 continue
             state = self._bucket_state(cap, idx)
             nt_max = max(nts)
-            xt = jnp.stack(
-                [jnp.pad(tests[i], ((0, nt_max - tests[i].shape[0]), (0, 0)))
-                 for i in idx]
-            )
+            with _tracer.span("stack"):
+                xt = jnp.stack(
+                    [jnp.pad(tests[i], ((0, nt_max - tests[i].shape[0]), (0, 0)))
+                     for i in idx]
+                )
             if self.method == "lowrank":
                 res = lowrank.predict_from_lowrank_state(
                     state, xt, full_cov=full_cov, n_streams=self.n_streams,

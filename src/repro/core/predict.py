@@ -44,10 +44,12 @@ from repro.core import kernels_math as km
 from repro.core import precision, tiling, triangular
 from repro.dist import sharding as dist_sharding
 
-# Dispatch-boundary trace spans (DESIGN.md §15).  The jnp fast paths run
-# the program under jit, so executor.run_program only executes at trace
-# time there — the per-dispatch record must happen HERE, at the host call
-# into the cached jitted fn, where operands are concrete.
+# Dispatch-boundary trace spans (DESIGN.md §15): ``pad`` (inputs into tile
+# chunks), the program's launch (``fused``, ``fused_batched``,
+# ``nlml_program``) and ``untile`` (outputs back to rows).  The jnp fast
+# paths run the program under jit, so executor.run_program only executes at
+# trace time there — the per-dispatch record must happen HERE, at the host
+# call into the cached jitted fn, where operands are concrete.
 _tracer = obs.Tracer("repro.predict")
 
 
@@ -337,7 +339,8 @@ def predict_from_state(
     if obs.enabled() and not isinstance(x_test, jax.core.Tracer):
         obs.inc("predict.warm_tail")
     dtype = state.x_chunks.dtype if dtype is None else jnp.dtype(dtype)
-    xtc = tiling.pad_features(x_test, state.m, dtype=dtype)
+    with _tracer.span("pad"):
+        xtc = tiling.pad_features(x_test, state.m, dtype=dtype)
     kstar = assemble_cross_tiles(
         xtc, state.x_chunks, params, nh, state.n, backend=backend, kernel=kernel
     )
@@ -351,7 +354,8 @@ def predict_from_state(
     w = triangular.tiled_gram(v)                               # (Q, Q, mq, mq)
     prior = assemble_prior_tiles(xtc, params, nh, backend=backend, kernel=kernel)
     sigma_tiles = prior - w
-    sigma = tiling.untile_dense(sigma_tiles)[:nh, :nh]
+    with _tracer.span("untile"):
+        sigma = tiling.untile_dense(sigma_tiles)[:nh, :nh]
     return mean, sigma
 
 
@@ -470,22 +474,24 @@ def predict_fused(
     n = x_train.shape[0]
     nh = x_test.shape[0]
     dtype = _resolve_dtype(dtype, x_train)
-    xc = tiling.pad_features(x_train, m, dtype=dtype)
-    yc = tiling.pad_vector(y_train, m, dtype=dtype)
-    xtc = tiling.pad_features(x_test, m, dtype=dtype)
+    with _tracer.span("pad"):
+        xc = tiling.pad_features(x_train, m, dtype=dtype)
+        yc = tiling.pad_vector(y_train, m, dtype=dtype)
+        xtc = tiling.pad_features(x_test, m, dtype=dtype)
     fn = _fused_program_fn(
         full_cov, n_streams, backend, update_dtype, n, nh, kernel=kernel
     )
     _record_program("run_program", xc, xtc.shape[-3], full_cov, n_streams, backend)
     with _tracer.span("fused"):
         env = fn(xc, yc, xtc, params)
-    mean = env["mean"].reshape(-1)[:nh]
-    if full_cov:
-        q_tiles = xtc.shape[0]
-        sigma_tiles = env["prior"].reshape(q_tiles, q_tiles, m, m)
-        result = (mean, tiling.untile_dense(sigma_tiles)[:nh, :nh])
-    else:
-        result = mean
+    with _tracer.span("untile"):
+        mean = env["mean"].reshape(-1)[:nh]
+        if full_cov:
+            q_tiles = xtc.shape[0]
+            sigma_tiles = env["prior"].reshape(q_tiles, q_tiles, m, m)
+            result = (mean, tiling.untile_dense(sigma_tiles)[:nh, :nh])
+        else:
+            result = mean
     if not with_state:
         return result
     # env["y"] holds beta after the in-place forward substitution (§7)
@@ -545,13 +551,14 @@ def predict_fused_batched(
     b, n = x_train.shape[0], x_train.shape[1]
     nh = x_test.shape[1]
     dtype = _resolve_dtype(dtype, x_train)
-    xc = tiling.pad_features(x_train, m, dtype=dtype)    # (B, M, m, D)
-    yc = tiling.pad_vector(y_train, m, dtype=dtype)      # (B, M, m)
-    xtc = tiling.pad_features(x_test, m, dtype=dtype)    # (B, Q, m, D)
-    if mesh is not None:
-        xc = dist_sharding.device_put_fleet(xc, mesh)
-        yc = dist_sharding.device_put_fleet(yc, mesh)
-        xtc = dist_sharding.device_put_fleet(xtc, mesh)
+    with _tracer.span("pad"):
+        xc = tiling.pad_features(x_train, m, dtype=dtype)    # (B, M, m, D)
+        yc = tiling.pad_vector(y_train, m, dtype=dtype)      # (B, M, m)
+        xtc = tiling.pad_features(x_test, m, dtype=dtype)    # (B, Q, m, D)
+        if mesh is not None:
+            xc = dist_sharding.device_put_fleet(xc, mesh)
+            yc = dist_sharding.device_put_fleet(yc, mesh)
+            xtc = dist_sharding.device_put_fleet(xtc, mesh)
     ragged = n_valid is not None
     if ragged:
         nv = jnp.asarray(n_valid, jnp.int32)
@@ -575,13 +582,14 @@ def predict_fused_batched(
         )
         with _tracer.span("fused_batched"):
             env = fn(xc, yc, xtc, params)
-    mean = env["mean"].reshape(b, -1)[:, :nh]
-    if full_cov:
-        q_tiles = xtc.shape[1]
-        sigma_tiles = env["prior"].reshape(b, q_tiles, q_tiles, m, m)
-        result = (mean, tiling.untile_dense(sigma_tiles)[:, :nh, :nh])
-    else:
-        result = mean
+    with _tracer.span("untile"):
+        mean = env["mean"].reshape(b, -1)[:, :nh]
+        if full_cov:
+            q_tiles = xtc.shape[1]
+            sigma_tiles = env["prior"].reshape(b, q_tiles, q_tiles, m, m)
+            result = (mean, tiling.untile_dense(sigma_tiles)[:, :nh, :nh])
+        else:
+            result = mean
     if not with_state:
         return result
     state = PosteriorState(
@@ -623,12 +631,13 @@ def predict_from_state_batched(
     if obs.enabled() and not isinstance(x_test, jax.core.Tracer):
         obs.inc("predict.warm_tail_batched")
     dtype = state.x_chunks.dtype if dtype is None else jnp.dtype(dtype)
-    xtc = tiling.pad_features(x_test, state.m, dtype=dtype)
     # the warm tail runs op-by-op (no enclosing jit): committing the test
     # block to the fleet layout is enough — the cached state buffers carry
     # their sharding out of the fused program and propagate it through the
     # assembly/matvec ops.
-    xtc = dist_sharding.device_put_fleet(xtc, mesh)
+    with _tracer.span("pad"):
+        xtc = tiling.pad_features(x_test, state.m, dtype=dtype)
+        xtc = dist_sharding.device_put_fleet(xtc, mesh)
     nv = state.n if state.n_valid is None else state.n_valid
     ntv = nh if nt_valid is None else nt_valid
     kstar = assemble_cross_tiles_batched(
@@ -645,7 +654,9 @@ def predict_from_state_batched(
     )
     w = triangular.tiled_gram(v)                         # (B, Q, Q, mq, mq)
     prior = assemble_prior_tiles_batched(xtc, params, ntv, kernel)
-    sigma = tiling.untile_dense(prior - w)[:, :nh, :nh]
+    sigma_tiles = prior - w
+    with _tracer.span("untile"):
+        sigma = tiling.untile_dense(sigma_tiles)[:, :nh, :nh]
     return mean, sigma
 
 
@@ -687,14 +698,15 @@ def nlml_program_env(
     kernel = km.resolve_kernel(kernel)
     n = x_train.shape[-2]
     dtype = _resolve_dtype(dtype, x_train)
-    xc = tiling.pad_features(x_train, m, dtype=dtype)
-    yc = tiling.pad_vector(y_train, m, dtype=dtype)
-    xtc = jnp.zeros(xc.shape[:-3] + (0, m, xc.shape[-1]), dtype)
-    if mesh is not None and xc.ndim == 4:
-        xc = dist_sharding.device_put_fleet(xc, mesh)
-        yc = dist_sharding.device_put_fleet(yc, mesh)
-    else:
-        mesh = None  # unbatched programs have no problem axis to shard
+    with _tracer.span("pad"):
+        xc = tiling.pad_features(x_train, m, dtype=dtype)
+        yc = tiling.pad_vector(y_train, m, dtype=dtype)
+        xtc = jnp.zeros(xc.shape[:-3] + (0, m, xc.shape[-1]), dtype)
+        if mesh is not None and xc.ndim == 4:
+            xc = dist_sharding.device_put_fleet(xc, mesh)
+            yc = dist_sharding.device_put_fleet(yc, mesh)
+        else:
+            mesh = None  # unbatched programs have no problem axis to shard
     if n_valid is not None:
         fn = _fused_program_fn(
             False, n_streams, backend, update_dtype, None, None,

@@ -1,9 +1,9 @@
 """repro.obs — zero-cost-when-off telemetry (DESIGN.md §15).
 
-A process-local metrics registry (counters / gauges / fixed-bucket
-histograms / JSON-lines events), profiler trace spans for the host
-dispatch boundaries, an lru-cache statistics snapshot, and the NLML-trend
-drift monitor.  A leaf package: it never imports ``repro.core`` (core
+A process-local metrics registry (counters / fixed-bucket histograms /
+JSON-lines events), profiler trace spans for the host dispatch boundaries
+and named scopes for the ops inside a program, an lru-cache statistics
+snapshot, and the NLML-trend drift monitor.  A leaf package: it never imports ``repro.core`` (core
 imports it), so instrumentation can thread through every layer without
 cycles.
 
@@ -27,7 +27,6 @@ from repro.obs.registry import (
     FRACTION_EDGES,
     MAX_EVENTS,
     Counter,
-    Gauge,
     Histogram,
     Registry,
     cache_stats,
@@ -41,7 +40,6 @@ from repro.obs.registry import (
     register_cache,
     registry,
     reset,
-    set_gauge,
     snapshot,
     to_json,
     to_prometheus,
@@ -54,7 +52,6 @@ __all__ = [
     "FRACTION_EDGES",
     "Counter",
     "DriftMonitor",
-    "Gauge",
     "Histogram",
     "MAX_EVENTS",
     "Registry",
@@ -70,7 +67,6 @@ __all__ = [
     "register_cache",
     "registry",
     "reset",
-    "set_gauge",
     "snapshot",
     "span",
     "to_json",

@@ -1,6 +1,6 @@
 """Process-local telemetry registry (DESIGN.md §15).
 
-Counters, gauges, fixed-bucket histograms and a structured JSON-lines event
+Counters, fixed-bucket histograms and a structured JSON-lines event
 log, plus a registry of the library's ``lru_cache``d plan/jit factories so
 plan-invariance regressions are observable at runtime (``cache_stats``).
 
@@ -50,18 +50,6 @@ class Counter:
 
     def inc(self, n: float = 1.0) -> None:
         self.value += n
-
-
-class Gauge:
-    """Last-written value."""
-
-    __slots__ = ("value",)
-
-    def __init__(self) -> None:
-        self.value = float("nan")
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
 
 
 class Histogram:
@@ -131,7 +119,6 @@ class Registry:
 
     def __init__(self) -> None:
         self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
         self.events: deque = deque(maxlen=MAX_EVENTS)
         self._sink = None
@@ -143,12 +130,6 @@ class Registry:
         if c is None:
             c = self._counters[name] = Counter()
         return c
-
-    def gauge(self, name: str) -> Gauge:
-        g = self._gauges.get(name)
-        if g is None:
-            g = self._gauges[name] = Gauge()
-        return g
 
     def histogram(self, name: str, edges: Optional[Sequence[float]] = None) -> Histogram:
         h = self._histograms.get(name)
@@ -181,7 +162,6 @@ class Registry:
     def snapshot(self) -> dict:
         return {
             "counters": {k: c.value for k, c in sorted(self._counters.items())},
-            "gauges": {k: g.value for k, g in sorted(self._gauges.items())},
             "histograms": {
                 k: h.to_dict() for k, h in sorted(self._histograms.items())
             },
@@ -198,10 +178,6 @@ class Registry:
             pn = _prom_name(name)
             lines.append(f"# TYPE {pn} counter")
             lines.append(f"{pn} {_prom_val(c.value)}")
-        for name, g in sorted(self._gauges.items()):
-            pn = _prom_name(name)
-            lines.append(f"# TYPE {pn} gauge")
-            lines.append(f"{pn} {_prom_val(g.value)}")
         for name, h in sorted(self._histograms.items()):
             pn = _prom_name(name)
             lines.append(f"# TYPE {pn} histogram")
@@ -216,7 +192,6 @@ class Registry:
 
     def clear(self) -> None:
         self._counters.clear()
-        self._gauges.clear()
         self._histograms.clear()
         self.events.clear()
         self.close_sink()
@@ -274,11 +249,6 @@ def reset() -> None:
 def inc(name: str, n: float = 1.0) -> None:
     if _enabled:
         _global.counter(name).inc(n)
-
-
-def set_gauge(name: str, v: float) -> None:
-    if _enabled:
-        _global.gauge(name).set(v)
 
 
 def observe(name: str, v: float, edges: Optional[Sequence[float]] = None) -> None:

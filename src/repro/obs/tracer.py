@@ -1,23 +1,23 @@
-"""Trace spans for the hot paths (DESIGN.md §15).
+"""Trace spans and op scopes for the hot paths (DESIGN.md §15).
 
 Spans wrap host *dispatch* boundaries in ``jax.profiler.TraceAnnotation``
-so the library's stages show up as named ranges in a jax profiler / perfetto
-capture — the live analogue of the paper's per-stage breakdown.  When
+so the library's front-end stages show up as named ranges in a jax
+profiler / perfetto capture, on the same clock as the device's ops.  When
 telemetry is disabled (the default) :func:`span` returns a shared no-op
 context manager: no allocation, no profiler calls, nothing.
 
 Spans are never opened inside jitted code: under jit the Python body runs
 only at trace time, so an in-program annotation would label tracing, not
-execution (why-no-instrumentation-inside-jit, DESIGN.md §15).  For scoping
-*within* a traced program jax's ``named_scope`` is the right tool — the
-:class:`Tracer` exposes it for completeness — but the repro's own
-instrumentation stays at dispatch boundaries.
+execution (why-no-instrumentation-inside-jit, DESIGN.md §15).  Inside a
+traced program :meth:`Tracer.named_scope` names the ops instead: the
+executor wraps every batch of its plans in ``repro.exec.<op family>``, which
+reaches each compiled op's metadata (``op_name``, and the profiler trace's
+``tf_op``) and changes nothing else, so it is always on.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
 
 import jax
 
@@ -58,16 +58,3 @@ class Tracer:
         """jax.named_scope — for use INSIDE traced code (names jaxpr ops);
         unconditional because it costs nothing at execution time."""
         return jax.named_scope(f"{self.prefix}.{name}")
-
-    def annotate(self, name: str):
-        """Decorator form of :meth:`span`."""
-
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapped(*args, **kwargs):
-                with self.span(name):
-                    return fn(*args, **kwargs)
-
-            return wrapped
-
-        return deco

@@ -4,6 +4,7 @@ counters, the jitter-retry recovery, and the NLML drift monitor — including
 the serving loop's automatic off-hot-path re-optimize.
 """
 
+import importlib
 import json
 import math
 
@@ -39,11 +40,8 @@ def test_counter_gauge_semantics():
     obs.enable()
     obs.inc("a")
     obs.inc("a", 4)
-    obs.set_gauge("g", 2.5)
-    obs.set_gauge("g", 7.0)  # gauge keeps the last write only
     snap = obs.snapshot()
     assert snap["counters"]["a"] == 5.0
-    assert snap["gauges"]["g"] == 7.0
 
 
 def test_disabled_helpers_record_nothing():
@@ -109,14 +107,12 @@ def test_jsonl_sink_round_trip(tmp_path):
 def test_to_json_and_prometheus():
     obs.enable()
     obs.inc("serve.requests", 3)
-    obs.set_gauge("pool.occupancy", 0.5)
     obs.observe("lat_ms", 2.0, edges=(1.0, 4.0))
     parsed = json.loads(obs.to_json())
     assert parsed["counters"]["serve.requests"] == 3.0
     prom = obs.to_prometheus()
     assert "# TYPE repro_serve_requests counter" in prom
     assert "repro_serve_requests 3" in prom
-    assert "repro_pool_occupancy 0.5" in prom
     # histogram exposition: cumulative buckets + +Inf + sum/count
     assert 'repro_lat_ms_bucket{le="4"} 1' in prom
     assert 'repro_lat_ms_bucket{le="+Inf"} 1' in prom
@@ -179,6 +175,68 @@ def test_cache_stats_reports_plan_caches():
     before = st["hits"]
     executor.program_plan(4, 1, False, 2)  # lru hit
     assert obs.cache_stats()["executor.program_plan"]["hits"] == before + 1
+
+
+# -- front-end spans ---------------------------------------------------------
+
+
+class _SpanLog:
+    """Stands in for the profiler's TraceAnnotation: (depth, name) as opened."""
+
+    def __init__(self):
+        self.opened, self._depth = [], 0
+
+    def __call__(self, name):
+        log = self
+
+        class _Span:
+            def __enter__(self):
+                log.opened.append((log._depth, name))
+                log._depth += 1
+
+            def __exit__(self, *exc):
+                log._depth -= 1
+
+        return _Span()
+
+
+@pytest.fixture
+def span_log(monkeypatch):
+    log = _SpanLog()
+    monkeypatch.setattr(importlib.import_module("repro.obs.tracer"), "_TraceAnnotation", log)
+    obs.enable()
+    return log
+
+
+def test_gp_predict_spans_nest_under_one_predict(rng, span_log):
+    x = rng.standard_normal((40, 2)).astype(np.float32)
+    y = rng.standard_normal(40).astype(np.float32)
+    xt = rng.standard_normal((20, 2)).astype(np.float32)
+    gp = GaussianProcess(x, y, params=PARAMS, tile_size=16)
+    gp.predict_with_uncertainty(xt)  # cold: the fused program
+    assert span_log.opened == [
+        (0, "repro.gp.predict"), (1, "repro.gp.lookup"), (1, "repro.predict.pad"),
+        (1, "repro.predict.fused"), (1, "repro.predict.untile"), (1, "repro.gp.diag"),
+    ]
+    span_log.opened.clear()
+    gp.predict(xt)  # warm: the tail off the cached factor
+    assert span_log.opened == [
+        (0, "repro.gp.predict"), (1, "repro.gp.lookup"), (1, "repro.predict.pad"),
+    ]
+
+
+def test_fleet_predict_spans_stack_and_bucket(rng, span_log):
+    fleet = _fleet(rng)
+    fleet.predict_with_uncertainty(rng.uniform(size=(5, 1)).astype(np.float32))
+    names = [name for _, name in span_log.opened]
+    assert [d for d, _ in span_log.opened].count(0) == 1
+    assert span_log.opened[0] == (0, "repro.gp.predict")
+    assert names[-1] == "repro.gp.diag"
+    buckets = len(fleet.bucket_assignment())
+    assert names.count("repro.gp.bucket") == buckets
+    # each cold bucket stacks its problems, then its test block
+    assert names.count("repro.gp.stack") == 2 * buckets
+    assert names.count("repro.predict.nlml_program") == buckets
 
 
 # -- factorization health ----------------------------------------------------

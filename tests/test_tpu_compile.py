@@ -9,12 +9,14 @@ one process may hold the TPU library at a time.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import executor
 from repro.core import kernels_math as km
 from repro.core import mll
 from repro.core import predict as pred
@@ -65,6 +67,27 @@ def test_fused_predict_compiles(one_chip):
         _params(one_chip),
     ).compile()
     assert _fits(compiled)
+
+
+def test_fused_predict_ops_carry_their_family(one_chip):
+    # every op the chip runs that came from the executor's plan names its op
+    # family (``repro.exec.<op>``): the profiler trace reads it as ``tf_op``
+    fn = pred._fused_program_fn(True, None, "jnp", None, N, N, kernel=km.resolve_kernel(None))
+    m_tiles = N // TILE
+    text = fn.lower(
+        _spec(one_chip, (m_tiles, TILE, D)),
+        _spec(one_chip, (m_tiles, TILE)),
+        _spec(one_chip, (m_tiles, TILE, D)),
+        _params(one_chip),
+    ).compile().as_text()
+    plan = executor.program_plan(m_tiles, m_tiles, True, None)
+    families = {bt.op for level in plan.levels for bt in level}
+    assert set(re.findall(r"repro\.exec\.(\w+)", text)) == families
+    work = [line for line in text.splitlines()
+            if re.search(r" (convolution|dot|custom-call|cholesky|triangular-solve)\(", line)
+            and "op_name=" in line]
+    assert len(work) > 20
+    assert all("repro.exec." in line for line in work)
 
 
 def test_tiled_nlml_grad_compiles(one_chip):
