@@ -86,9 +86,9 @@ def run(m_tiles: int = 16, out=print):
     }
     q_tiles = max(m_tiles // 4, 1)
     for ns in (4, 16):
-        plan = executor.program_plan(m_tiles, q_tiles, True, ns)
+        plan = executor.program_plan(m_tiles, q_tiles, sch.Head.COVARIANCE, ns)
         staged = executor.staged_launch_count(
-            m_tiles, uncertainty=True, n_streams=ns
+            m_tiles, head=sch.Head.COVARIANCE, n_streams=ns
         )
         mixed = 0
         trace = []

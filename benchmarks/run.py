@@ -91,7 +91,7 @@ def _executor_counts(tile_counts=(4, 8, 16), streams=(None, 4, 16)) -> list:
                     "fused_batches": plan.n_batches,
                     "fused_waves": len(plan.levels),
                     "staged_batches": executor.staged_launch_count(
-                        m_tiles, uncertainty=unc, n_streams=ns
+                        m_tiles, head=unc, n_streams=ns
                     ),
                 })
     return rows

@@ -34,8 +34,9 @@ latency, the number the streaming subsystem exists to shrink.
 
 ``--metrics out.jsonl`` enables `repro.obs` telemetry (DESIGN.md §15) for
 the run and streams every event — executor wave dispatches, `serve.wave`
-records, factorization-health incidents, a final lru-cache snapshot — to a
-JSON-lines file:
+records, factorization-health incidents, the predict-path counters (warm
+tails, ``predict.head.variance`` / ``.covariance`` dispatches), a final
+lru-cache snapshot — to a JSON-lines file:
 
     PYTHONPATH=src python examples/serve_gp.py --ragged 8 --metrics metrics.jsonl
 """
@@ -287,6 +288,14 @@ def main():
                 counters={
                     k: v for k, v in snap["counters"].items()
                     if k.startswith("health.")
+                },
+            )
+            # how often each predict path ran: warm tails, uncertainty heads
+            obs.event(
+                "serve.predict",
+                counters={
+                    k: v for k, v in snap["counters"].items()
+                    if k.startswith("predict.")
                 },
             )
             obs.event("obs.cache_stats", caches=obs.cache_stats())

@@ -478,7 +478,10 @@ def _trsv_batch(lii: jax.Array, x: jax.Array, transpose: bool) -> jax.Array:
 #   "cross"   (Q*M, m, m)     cross-covariance tile grid K_{X̂,X} (flat)
 #   "mean"    (Q, m)          predictive-mean chunks
 #   "v"       (M, Q, m, m)    uncertainty workspace V = L^{-1} K_{X,X̂}
-#   "prior"   (Q*Q, m, m)     prior test tiles -> posterior covariance tiles
+#   "prior"   (Q*Q, m, m)     covariance head: prior test tiles -> posterior
+#                             covariance tiles
+#             (Q, m)          variance head: prior diagonal -> posterior
+#                             variance chunks
 #
 # plus the read-only feature blocks xc (M, m, D) / xtc (Q, m, D).  One
 # run_program walks the fused schedule issuing per-level multi-op batches;
@@ -491,9 +494,11 @@ TRAIL = sch.TRAIL_GROUP  # fused SYRK+GEMM dispatch group (program plans only)
 
 
 def _program_batch(
-    op: str, tasks: Sequence[sch.Task], m: int, q_tiles: int
+    op: str, tasks: Sequence[sch.Task], m: int, q_tiles: int, head: sch.Head
 ) -> Batch:
-    """Gather/scatter indices of one program batch (buffer roles fixed by op)."""
+    """Gather/scatter indices of one program batch (buffer roles fixed by op;
+    a PRIOR tile lands in the covariance head's flat Q x Q grid, or in the
+    variance head's row p of diagonal chunks)."""
     slot = tiling.packed_index
     tasks = tuple(tasks)
     if op in (sch.POTRF, sch.TRSM):
@@ -530,6 +535,8 @@ def _program_batch(
     if op == sch.PRIOR:
         p = _arr([i for _, i, _, _ in tasks])
         q = _arr([j for _, _, j, _ in tasks])
+        if head == sch.Head.VARIANCE:
+            return Batch(op, tasks, out=p, a=p, b=q)
         return Batch(
             op, tasks, out=_arr([i * q_tiles + j for _, i, j, _ in tasks]), a=p, b=q
         )
@@ -554,28 +561,30 @@ def _program_batch(
 def program_plan(
     m_tiles: int,
     q_tiles: int,
-    uncertainty: bool = False,
+    head: sch.Head = sch.Head.NONE,
     n_streams: Optional[int] = None,
 ) -> Plan:
     """Compile the fused prediction program into batched launches.
 
-    ``None``: ASAP levels of the whole-pipeline DAG (cross tiles at level 0
-    alongside assembly, solve rows leveled against the columns that produce
-    their tiles).  Finite: the cross-stage wavefront schedule — waves of
-    <= n_streams simultaneously-ready tasks, critical-path first, so solve
-    rows and cross assembly ride the tail of Cholesky columns (paper Fig. 5).
+    ``head`` picks the closing uncertainty head (:class:`scheduler.Head`; a
+    bool still reads as the old ``uncertainty`` flag) and joins the lru key.
+    ``n_streams=None``: ASAP levels of the whole-pipeline DAG (cross tiles
+    at level 0 alongside assembly, solve rows leveled against the columns
+    that produce their tiles).  Finite: the cross-stage wavefront schedule
+    — waves of <= n_streams simultaneously-ready tasks, critical-path
+    first, so solve rows and cross assembly ride the tail of Cholesky
+    columns (paper Fig. 5).
     """
+    head = sch.Head(head)
     if n_streams is None:
-        schedule = sch.build_program_schedule(
-            m_tiles, q_tiles, uncertainty=uncertainty
-        )
+        schedule = sch.build_program_schedule(m_tiles, q_tiles, head=head)
     else:
         schedule = sch.build_wavefront_schedule(
             m_tiles,
             n_streams,
             kind="program",
             q_tiles=q_tiles,
-            uncertainty=uncertainty,
+            head=head,
         )
     levels = []
     for level in schedule.levels:
@@ -588,7 +597,7 @@ def program_plan(
             # pool size (see scheduler.BULK_OPS) — never chunk them.
             width = None if gop in sch.BULK_OPS else n_streams
             for chunk in sch.chunk_tasks(tasks, width):
-                batches.append(_program_batch(gop, chunk, m_tiles, q_tiles))
+                batches.append(_program_batch(gop, chunk, m_tiles, q_tiles, head))
         levels.append(tuple(batches))
     return Plan("program", m_tiles, n_streams, tuple(levels))
 
@@ -648,21 +657,22 @@ def run_lowrank_contraction(
 
 
 def staged_launch_count(
-    m_tiles: int, *, uncertainty: bool = False, n_streams: Optional[int] = None
+    m_tiles: int, *, head: sch.Head = sch.Head.NONE, n_streams: Optional[int] = None
 ) -> int:
     """Batched launches the *staged* pipeline issues end-to-end.
 
     One covariance assembly + the factorization plan + both vector-solve
-    plans + cross assembly + mean matvec; with uncertainty also the prior
-    assembly, the B-tile transpose pack, the matrix forward-solve plan, the
-    gram einsum and the prior - W subtraction.  The fused program plan must
-    beat this strictly for M >= 8 (tests/test_executor.py).
+    plans + cross assembly + mean matvec; with either uncertainty head also
+    the prior assembly, the B-tile transpose pack, the matrix forward-solve
+    plan, the gram (V^T V, or the column sums of V squared) and the
+    subtraction from the prior.  The fused program plan must beat this
+    strictly for M >= 8 (tests/test_executor.py).
     """
     n = 1 + cholesky_plan(m_tiles, n_streams).n_batches
     n += solve_plan(m_tiles, lower=True, n_streams=n_streams).n_batches
     n += solve_plan(m_tiles, lower=False, n_streams=n_streams).n_batches
     n += 1 + 1  # cross assembly, mean matvec
-    if uncertainty:
+    if head != sch.Head.NONE:
         n += 1 + 1  # prior assembly, B-tile transpose pack
         n += solve_plan(m_tiles, lower=True, n_streams=n_streams).n_batches
         n += 1 + 1  # gram, prior - W subtraction
@@ -815,7 +825,7 @@ def run_program(
     n_valid,
     nt_valid,
     *,
-    uncertainty: bool = False,
+    head: sch.Head = sch.Head.NONE,
     n_streams: Optional[int] = None,
     backend: str = "jnp",
     update_dtype=None,
@@ -829,8 +839,10 @@ def run_program(
     target blocks; ``n_valid`` / ``nt_valid`` the unpadded row counts.
     Returns the final buffer environment (see module section docstring):
     ``env["mean"]`` holds the predictive-mean chunks, ``env["prior"]`` the
-    posterior-covariance tiles (uncertainty only), and ``env["packed"]`` /
-    ``env["alpha"]`` the factor/weights slices a PosteriorState caches.
+    head's output — the posterior-covariance tiles (``Head.COVARIANCE``) or
+    the posterior-variance chunks (``Head.VARIANCE``) — and
+    ``env["packed"]`` / ``env["alpha"]`` the factor/weights slices a
+    PosteriorState caches.
 
     **Problem batching:** with xc (B, M, m, D) / yc (B, M, m) /
     xtc (B, Q, m, D) — B independent problems of identical tile geometry —
@@ -862,7 +874,8 @@ def run_program(
     batched = xc.ndim == 4
     m_tiles, m = xc.shape[-3], xc.shape[-2]
     q_tiles = xtc.shape[-3]
-    plan = program_plan(m_tiles, q_tiles, uncertainty, n_streams)
+    head = sch.Head(head)
+    plan = program_plan(m_tiles, q_tiles, head, n_streams)
     if obs.enabled():
         if isinstance(xc, jax.core.Tracer):
             obs.inc("executor.traces.run_program")
@@ -895,9 +908,12 @@ def run_program(
         "cross": shard(jnp.zeros(lead + (q_tiles * m_tiles, m, m), dtype)),
         "mean": shard(jnp.zeros(lead + (q_tiles, m), dtype)),
     }
-    if uncertainty:
+    if head != sch.Head.NONE:
         env["v"] = shard(jnp.zeros(lead + (m_tiles, q_tiles, m, m), dtype))
+    if head == sch.Head.COVARIANCE:
         env["prior"] = shard(jnp.zeros(lead + (q_tiles * q_tiles, m, m), dtype))
+    elif head == sch.Head.VARIANCE:
+        env["prior"] = shard(jnp.zeros(lead + (q_tiles, m), dtype))
 
     def off(idx):  # tile index -> global row/col offset, i32 on device
         return jnp.asarray(idx * m, jnp.int32)
@@ -917,6 +933,8 @@ def run_program(
                     env["cross"] = put(env["cross"], bt.out, tiles)
                 elif op == sch.PRIOR:
                     tiles = priorf(take(xtc, bt.a), take(xtc, bt.b), off(bt.a), off(bt.b))
+                    if head == sch.Head.VARIANCE:  # diagonal tiles: keep k(x, x)
+                        tiles = jnp.diagonal(tiles, axis1=-2, axis2=-1)
                     env["prior"] = put(env["prior"], bt.out, tiles)
                 elif op == sch.POTRF:
                     env["packed"] = put(packed, bt.out, potrf_b(take(packed, bt.a)))
@@ -971,6 +989,11 @@ def run_program(
                         f"{Z}gab,{Z}gqbc->{Z}gqac", take(packed, bt.a), take(env["v"], bt.b)
                     )
                     env["v"] = add(env["v"], bt.out, -upd.astype(dtype))
+                elif op == sch.GRAM and head == sch.Head.VARIANCE:
+                    # diag(V^T V): column sums of V squared, element-wise in
+                    # the buffer dtype — no Q x Q product is formed
+                    vsq = jnp.sum(jnp.square(env["v"]), axis=(-4, -2))
+                    env["prior"] = env["prior"] - vsq
                 elif op == sch.GRAM:
                     w = jnp.einsum(f"{Z}ipab,{Z}iqac->{Z}pqbc", env["v"], env["v"])
                     env["prior"] = env["prior"] - w.reshape(

@@ -42,11 +42,23 @@ from repro.core import kernels_math as km
 from repro.core import lowrank
 from repro.core import predict as pred
 from repro.core import tiling
+from repro.core.scheduler import Head
 
 # Host spans of the predict calls (DESIGN.md §15): one ``repro.gp.predict``
 # per call, around the cache lookup, the program's pad/launch/untile spans
-# (``repro.predict.*``) and the variance diagonal.
+# (``repro.predict.*``) and, closing ``predict_with_uncertainty``, ``diag``:
+# the variance handed back (the variance head computes it directly, so the
+# span holds no work on the tiled path).
 _tracer = obs.Tracer("repro.gp")
+
+
+def _variance_from_full(out, head: Head):
+    """The low-rank tier and the monolithic pipeline compute only the full
+    covariance: for the variance head, keep its diagonal."""
+    if head != Head.VARIANCE:
+        return out
+    mean, sigma = out
+    return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
 
 def _lowrank_state_with_retry(build, base_jitter: float) -> lowrank.LowRankState:
     """Cold Nyström build with escalating-jitter retries (DESIGN.md §15).
@@ -398,7 +410,7 @@ class GaussianProcess:
 
     # -- prediction ---------------------------------------------------------
 
-    def _predict_tiled(self, x_test: jax.Array, full_cov: bool):
+    def _predict_tiled(self, x_test: jax.Array, head: Head):
         """Route a tiled prediction: cached factor -> staged tail stages;
         cold + ``fused`` -> one whole-pipeline program whose buffer env also
         populates the posterior cache; cold staged -> posterior() then tail."""
@@ -416,7 +428,7 @@ class GaussianProcess:
                 x_test,
                 self.params,
                 self.tile_size,
-                full_cov=full_cov,
+                head=head,
                 n_streams=self.n_streams,
                 backend=self.op_backend,
                 update_dtype=self.update_dtype,
@@ -431,7 +443,7 @@ class GaussianProcess:
         return pred.predict_from_state(
             state,
             x_test,
-            full_cov=full_cov,
+            head=head,
             n_streams=self.n_streams,
             backend=self.op_backend,
             dtype=self.dtype,
@@ -447,31 +459,35 @@ class GaussianProcess:
             dtype=self.dtype,
         )
 
-    def _predict(self, x_test: jax.Array, full_cov: bool):
+    def _predict(self, x_test: jax.Array, head: Head):
         x_test = self._prep(x_test)
+        full_cov = head != Head.NONE
         if self.method == "lowrank":
-            return self._predict_lowrank(x_test, full_cov)
+            return _variance_from_full(self._predict_lowrank(x_test, full_cov), head)
         if self.pipeline == "monolithic":
-            return pred.predict_monolithic(
+            out = pred.predict_monolithic(
                 self.x_train, self.y_train, x_test, self.params,
                 full_cov=full_cov, dtype=self.dtype, kernel=self.kernel,
             )
-        return self._predict_tiled(x_test, full_cov)
+            return _variance_from_full(out, head)
+        return self._predict_tiled(x_test, head)
 
     def predict(self, x_test: jax.Array) -> jax.Array:
         with _tracer.span("predict"):
-            return self._predict(x_test, full_cov=False)
+            return self._predict(x_test, Head.NONE)
 
     def predict_full_cov(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """The paper's *Predict with Full Covariance Matrix* operation."""
         with _tracer.span("predict"):
-            return self._predict(x_test, full_cov=True)
+            return self._predict(x_test, Head.COVARIANCE)
 
     def predict_with_uncertainty(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """Means and posterior variances (n̂,): the variance head, which
+        never forms the n̂ x n̂ covariance on the tiled path."""
         with _tracer.span("predict"):
-            mean, sigma = self._predict(x_test, full_cov=True)
+            mean, var = self._predict(x_test, Head.VARIANCE)
             with _tracer.span("diag"):
-                return mean, jnp.diagonal(sigma)
+                return mean, var
 
     # -- hyperparameters ----------------------------------------------------
 
@@ -894,20 +910,21 @@ class GPBatch:
 
     # -- prediction ---------------------------------------------------------
 
-    def _predict_batched(self, x_test: jax.Array, full_cov: bool):
+    def _predict_batched(self, x_test: jax.Array, head: Head):
         """Cold: ONE problem-batched fused program (populates the posterior
         cache from its buffer env).  Warm: batched cross/mean tail off the
         cached stacked factor."""
         if self.method == "lowrank":
-            return lowrank.predict_from_lowrank_state(
+            out = lowrank.predict_from_lowrank_state(
                 self.lowrank_posterior(),
                 x_test,
-                full_cov=full_cov,
+                full_cov=head != Head.NONE,
                 n_streams=self.n_streams,
                 backend=self.op_backend,
                 dtype=self.dtype,
                 batch_dispatch=self.batch_dispatch,
             )
+            return _variance_from_full(out, head)
         with _tracer.span("lookup"):
             key = self._cache_key()
             warm = self._posterior is not None and self._posterior_key == key
@@ -916,7 +933,7 @@ class GPBatch:
             return pred.predict_from_state_batched(
                 self._posterior,
                 x_test,
-                full_cov=full_cov,
+                head=head,
                 n_streams=self.n_streams,
                 dtype=self.dtype,
                 mesh=self.mesh,
@@ -928,7 +945,7 @@ class GPBatch:
             x_test,
             self.params,
             self.tile_size,
-            full_cov=full_cov,
+            head=head,
             n_streams=self.n_streams,
             backend=self.op_backend,
             update_dtype=self.update_dtype,
@@ -946,18 +963,19 @@ class GPBatch:
 
         A shared (n̂, D) test block is broadcast to every problem."""
         with _tracer.span("predict"):
-            return self._predict_batched(self._prep(x_test), full_cov=False)
+            return self._predict_batched(self._prep(x_test), Head.NONE)
 
     def predict_full_cov(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Means (B, n̂) and posterior covariances (B, n̂, n̂)."""
         with _tracer.span("predict"):
-            return self._predict_batched(self._prep(x_test), full_cov=True)
+            return self._predict_batched(self._prep(x_test), Head.COVARIANCE)
 
     def predict_with_uncertainty(self, x_test: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """Means and posterior variances (B, n̂), from the variance head."""
         with _tracer.span("predict"):
-            mean, sigma = self._predict_batched(self._prep(x_test), full_cov=True)
+            mean, var = self._predict_batched(self._prep(x_test), Head.VARIANCE)
             with _tracer.span("diag"):
-                return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
+                return mean, var
 
     # -- hyperparameters ----------------------------------------------------
 
@@ -1269,50 +1287,56 @@ class GPFleet:
             )
         return x_test
 
-    def _predict_shared(self, x_test, full_cov):
+    def _predict_shared(self, x_test, head: Head):
         """One shared (n̂, D) test block evaluated under every problem."""
         x_test = self._prep_shared(x_test)
         nh = x_test.shape[0]
         b = self.batch_size
         mean = jnp.zeros((b, nh), self.dtype)
-        sigma = jnp.zeros((b, nh, nh), self.dtype) if full_cov else None
+        unc = None  # the head's output: (B, n̂, n̂) covariances or (B, n̂) variances
+        if head == Head.COVARIANCE:
+            unc = jnp.zeros((b, nh, nh), self.dtype)
+        elif head == Head.VARIANCE:
+            unc = jnp.zeros((b, nh), self.dtype)
         for cap, idx in self.bucket_assignment().items():
             state = self._bucket_state(cap, idx)
             with _tracer.span("stack"):
                 xt = jnp.broadcast_to(x_test[None], (len(idx),) + x_test.shape)
             if self.method == "lowrank":
                 out = lowrank.predict_from_lowrank_state(
-                    state, xt, full_cov=full_cov, n_streams=self.n_streams,
+                    state, xt, full_cov=head != Head.NONE, n_streams=self.n_streams,
                     backend=self.op_backend, dtype=self.dtype,
                     batch_dispatch=self.batch_dispatch,
                 )
+                out = _variance_from_full(out, head)
             else:
                 out = pred.predict_from_state_batched(
-                    state, xt, full_cov=full_cov,
+                    state, xt, head=head,
                     n_streams=self.n_streams, dtype=self.dtype, mesh=self.mesh,
                 )
             gather = jnp.asarray(idx)
-            if full_cov:
+            if unc is not None:
                 mean = mean.at[gather].set(out[0])
-                sigma = sigma.at[gather].set(out[1])
+                unc = unc.at[gather].set(out[1])
             else:
                 mean = mean.at[gather].set(out)
-        return (mean, sigma) if full_cov else mean
+        return mean if unc is None else (mean, unc)
 
     def predict(self, x_test) -> jax.Array:
         """Means (B, n̂) for one shared (n̂, D) test block."""
         with _tracer.span("predict"):
-            return self._predict_shared(x_test, full_cov=False)
+            return self._predict_shared(x_test, Head.NONE)
 
     def predict_full_cov(self, x_test) -> Tuple[jax.Array, jax.Array]:
         with _tracer.span("predict"):
-            return self._predict_shared(x_test, full_cov=True)
+            return self._predict_shared(x_test, Head.COVARIANCE)
 
     def predict_with_uncertainty(self, x_test) -> Tuple[jax.Array, jax.Array]:
+        """Means and posterior variances (B, n̂), from the variance head."""
         with _tracer.span("predict"):
-            mean, sigma = self._predict_shared(x_test, full_cov=True)
+            mean, var = self._predict_shared(x_test, Head.VARIANCE)
             with _tracer.span("diag"):
-                return mean, jnp.diagonal(sigma, axis1=-2, axis2=-1)
+                return mean, var
 
     def predict_each(self, x_test_list, *, full_cov: bool = False):
         """Per-problem test sets (list of (n̂_i, D)); ragged n̂_i are padded
@@ -1367,7 +1391,8 @@ class GPFleet:
                 )
             else:
                 res = pred.predict_from_state_batched(
-                    state, xt, full_cov=full_cov, n_streams=self.n_streams,
+                    state, xt, head=Head.COVARIANCE if full_cov else Head.NONE,
+                    n_streams=self.n_streams,
                     dtype=self.dtype, nt_valid=jnp.asarray(nts, jnp.int32),
                     mesh=self.mesh,
                 )

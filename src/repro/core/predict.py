@@ -7,7 +7,9 @@ Pipeline (all stages jit-compiled, data stays on device end-to-end):
   3. forward / backward substitution      L beta = y;  L^T alpha = beta
   4. cross covariance                     K_* = K_{X̂,X}          (tiled)
   5. predictive mean                      ŷ = K_* alpha
-  6. (uncertainty) solve L V = K_{X,X̂};  W = V^T V;  Σ = K_{X̂,X̂} - W
+  6. (uncertainty) solve L V = K_{X,X̂}, then one head (scheduler.Head):
+     covariance  Σ = K_{X̂,X̂} - V^T V
+     variance    diag(Σ) = diag(K_{X̂,X̂}) - colsum(V ∘ V)
 
 Two execution strategies (DESIGN.md §7):
 
@@ -42,6 +44,7 @@ from repro.core import cholesky as chol
 from repro.core import executor
 from repro.core import kernels_math as km
 from repro.core import precision, tiling, triangular
+from repro.core.scheduler import Head
 from repro.dist import sharding as dist_sharding
 
 # Dispatch-boundary trace spans (DESIGN.md §15): ``pad`` (inputs into tile
@@ -53,7 +56,7 @@ from repro.dist import sharding as dist_sharding
 _tracer = obs.Tracer("repro.predict")
 
 
-def _record_program(kind, xc, q_tiles, uncertainty, n_streams, backend):
+def _record_program(kind, xc, q_tiles, head, n_streams, backend):
     """Record one jitted fused-program dispatch (no-op unless obs is on).
 
     Skipped at trace time (``xc`` a tracer — when this caller is itself
@@ -64,10 +67,18 @@ def _record_program(kind, xc, q_tiles, uncertainty, n_streams, backend):
     if obs.enabled() and backend == "jnp" and not isinstance(xc, jax.core.Tracer):
         executor.record_dispatch(
             kind,
-            executor.program_plan(xc.shape[-3], q_tiles, uncertainty, n_streams),
+            executor.program_plan(xc.shape[-3], q_tiles, head, n_streams),
             backend=backend,
             batched=xc.ndim == 4,
         )
+
+
+def _count_head(head: Head, operand) -> None:
+    """Count one host dispatch of an uncertainty head, fused or warm:
+    ``predict.head.variance`` / ``predict.head.covariance`` (no-op unless
+    obs is on; skipped at trace time, like ``predict.warm_tail``)."""
+    if head != Head.NONE and obs.enabled() and not isinstance(operand, jax.core.Tracer):
+        obs.inc(f"predict.head.{head.name.lower()}")
 
 
 # ---------------------------------------------------------------------------
@@ -169,6 +180,25 @@ def assemble_prior_tiles(
     return jax.vmap(one)(xt_chunks, jnp.arange(mh) * m)
 
 
+def assemble_prior_diagonal(
+    xt_chunks: jax.Array, params, nt_valid, *, kernel: Optional[km.Kernel] = None
+) -> jax.Array:
+    """Diagonal of the prior K_{X̂,X̂} as chunks (Mhat, m), padded rows 0.
+
+    Only the Mhat diagonal tiles are assembled — as the full grid assembles
+    them, so every kernel, stationary or not, keeps its own k(x, x).
+    """
+    mh, m, _ = xt_chunks.shape
+    row0 = jnp.arange(mh) * m
+    tiles = jax.vmap(
+        lambda xa, r0: _tile_kernel(
+            xa, xa, r0, r0, params, nt_valid, nt_valid, symmetric=False,
+            kernel=kernel,
+        )
+    )(xt_chunks, row0)
+    return jnp.diagonal(tiles, axis1=-2, axis2=-1)
+
+
 # per-problem (B,) leaf normalization — canonical impl in kernels_math
 _broadcast_params = km.broadcast_params
 
@@ -211,6 +241,18 @@ def assemble_prior_tiles_batched(
     ntb = jnp.broadcast_to(jnp.asarray(nt_valid), (b,))
     return jax.vmap(
         lambda xt1, p, nt1: assemble_prior_tiles(xt1, p, nt1, kernel=kernel)
+    )(xt_chunks, params, ntb)
+
+
+def assemble_prior_diagonal_batched(
+    xt_chunks: jax.Array, params, nt_valid, kernel: Optional[km.Kernel] = None
+) -> jax.Array:
+    """Problem-batched prior diagonal chunks (B, Mhat, m)."""
+    b = xt_chunks.shape[0]
+    params = _broadcast_params(params, b, kernel)
+    ntb = jnp.broadcast_to(jnp.asarray(nt_valid), (b,))
+    return jax.vmap(
+        lambda xt1, p, nt1: assemble_prior_diagonal(xt1, p, nt1, kernel=kernel)
     )(xt_chunks, params, ntb)
 
 
@@ -321,7 +363,7 @@ def predict_from_state(
     state: PosteriorState,
     x_test: jax.Array,
     *,
-    full_cov: bool = False,
+    head: Head = Head.NONE,
     n_streams: Optional[int] = None,
     backend: str = "jnp",
     dtype=None,
@@ -331,13 +373,16 @@ def predict_from_state(
     The kernel hyperparameters come from the state itself — alpha and the
     factor are only valid for the params K was assembled with, so accepting
     them separately would invite a silent mismatch.  ``dtype=None`` follows
-    the state's storage dtype.
+    the state's storage dtype.  Returns the mean, ``(mean, var)`` for
+    ``Head.VARIANCE`` or ``(mean, sigma)`` for ``Head.COVARIANCE``.
     """
     params = state.params
     kernel = state.kernel
     nh = x_test.shape[0]
+    head = Head(head)
     if obs.enabled() and not isinstance(x_test, jax.core.Tracer):
         obs.inc("predict.warm_tail")
+    _count_head(head, x_test)
     dtype = state.x_chunks.dtype if dtype is None else jnp.dtype(dtype)
     with _tracer.span("pad"):
         xtc = tiling.pad_features(x_test, state.m, dtype=dtype)
@@ -345,12 +390,17 @@ def predict_from_state(
         xtc, state.x_chunks, params, nh, state.n, backend=backend, kernel=kernel
     )
     mean = triangular.tiled_matvec(kstar, state.alpha).reshape(-1)[:nh]
-    if not full_cov:
+    if head == Head.NONE:
         return mean
 
     # L V = K_{X,X̂}:  B tiles are the transpose grid of K_* tiles.
     b_tiles = jnp.einsum("qiab->iqba", kstar)
     v = triangular.forward_substitution_matrix(state.lpacked, b_tiles, n_streams=n_streams)
+    if head == Head.VARIANCE:
+        prior = assemble_prior_diagonal(xtc, params, nh, kernel=kernel)
+        var = prior - triangular.tiled_gram_diag(v)            # (Q, mq)
+        with _tracer.span("untile"):
+            return mean, var.reshape(-1)[:nh]
     w = triangular.tiled_gram(v)                               # (Q, Q, mq, mq)
     prior = assemble_prior_tiles(xtc, params, nh, backend=backend, kernel=kernel)
     sigma_tiles = prior - w
@@ -366,7 +416,7 @@ def predict_from_state(
 
 @functools.lru_cache(maxsize=None)
 def _fused_program_fn(
-    uncertainty: bool,
+    head: Head,
     n_streams: Optional[int],
     backend: str,
     update_dtype,
@@ -402,6 +452,9 @@ def _fused_program_fn(
     the lru key too — each covariance family gets its own jit — while the
     executor's Plan caches stay kernel-invariant (only ASSEMBLE/CROSS/PRIOR
     payloads differ).
+
+    ``head`` (:class:`repro.core.scheduler.Head`) is the program's
+    uncertainty head: each head is its own program and its own jit.
     """
     if n_valid is None:
 
@@ -413,7 +466,7 @@ def _fused_program_fn(
                 params,
                 nv,
                 ntv,
-                uncertainty=uncertainty,
+                head=head,
                 n_streams=n_streams,
                 backend=backend,
                 update_dtype=update_dtype,
@@ -433,7 +486,7 @@ def _fused_program_fn(
             params,
             n_valid,
             nt_valid,
-            uncertainty=uncertainty,
+            head=head,
             n_streams=n_streams,
             backend=backend,
             update_dtype=update_dtype,
@@ -453,7 +506,7 @@ def predict_fused(
     params,
     m: int,
     *,
-    full_cov: bool = False,
+    head: Head = Head.NONE,
     n_streams: Optional[int] = None,
     backend: str = "jnp",
     update_dtype=None,
@@ -465,12 +518,14 @@ def predict_fused(
 
     Runs assembly, factorization, both substitutions, cross covariance and
     the prediction heads as a single multi-stage program with cross-stage
-    wavefronts (executor.run_program).  Returns mean (or ``(mean, sigma)``
-    with ``full_cov``); with ``with_state=True`` also the
+    wavefronts (executor.run_program).  Returns the mean, ``(mean, var)``
+    for ``head=Head.VARIANCE`` or ``(mean, sigma)`` for
+    ``Head.COVARIANCE``; with ``with_state=True`` also the
     :class:`PosteriorState` sliced out of the program's buffer environment,
     so callers can reuse the factor for later staged predictions.
     """
     kernel = km.resolve_kernel(kernel)
+    head = Head(head)
     n = x_train.shape[0]
     nh = x_test.shape[0]
     dtype = _resolve_dtype(dtype, x_train)
@@ -479,17 +534,20 @@ def predict_fused(
         yc = tiling.pad_vector(y_train, m, dtype=dtype)
         xtc = tiling.pad_features(x_test, m, dtype=dtype)
     fn = _fused_program_fn(
-        full_cov, n_streams, backend, update_dtype, n, nh, kernel=kernel
+        head, n_streams, backend, update_dtype, n, nh, kernel=kernel
     )
-    _record_program("run_program", xc, xtc.shape[-3], full_cov, n_streams, backend)
+    _record_program("run_program", xc, xtc.shape[-3], head, n_streams, backend)
+    _count_head(head, xc)
     with _tracer.span("fused"):
         env = fn(xc, yc, xtc, params)
     with _tracer.span("untile"):
         mean = env["mean"].reshape(-1)[:nh]
-        if full_cov:
+        if head == Head.COVARIANCE:
             q_tiles = xtc.shape[0]
             sigma_tiles = env["prior"].reshape(q_tiles, q_tiles, m, m)
             result = (mean, tiling.untile_dense(sigma_tiles)[:nh, :nh])
+        elif head == Head.VARIANCE:
+            result = (mean, env["prior"].reshape(-1)[:nh])
         else:
             result = mean
     if not with_state:
@@ -509,7 +567,7 @@ def predict_fused_batched(
     params,
     m: int,
     *,
-    full_cov: bool = False,
+    head: Head = Head.NONE,
     n_streams: Optional[int] = None,
     backend: str = "jnp",
     update_dtype=None,
@@ -534,7 +592,8 @@ def predict_fused_batched(
     per-problem valid training counts — when the stacked problems are
     zero-padded to a shared bucket capacity; rows past each frontier must
     be zero.  ``nt_valid`` optionally masks per-problem test counts the
-    same way (mean/sigma rows past a problem's own count come back zero).
+    same way (mean/var/sigma rows past a problem's own count come back
+    zero).
     The frontiers are traced operands: every size mix of the same stacked
     shape shares one jit trace and one executor Plan.
 
@@ -543,11 +602,13 @@ def predict_fused_batched(
     buffer to it inside the program — pure data parallelism, zero
     collectives, one Plan regardless of device count.
 
-    Returns mean (B, n̂), or ``(mean, sigma)`` with sigma (B, n̂, n̂) when
-    ``full_cov``; with ``with_state=True`` also the stacked
+    Returns mean (B, n̂), ``(mean, var)`` with var (B, n̂) for
+    ``Head.VARIANCE``, or ``(mean, sigma)`` with sigma (B, n̂, n̂) for
+    ``Head.COVARIANCE``; with ``with_state=True`` also the stacked
     :class:`PosteriorState` (leading B axis on lpacked/alpha/x_chunks).
     """
     kernel = km.resolve_kernel(kernel)
+    head = Head(head)
     b, n = x_train.shape[0], x_train.shape[1]
     nh = x_test.shape[1]
     dtype = _resolve_dtype(dtype, x_train)
@@ -564,30 +625,34 @@ def predict_fused_batched(
         nv = jnp.asarray(n_valid, jnp.int32)
         ntv = jnp.asarray(nh if nt_valid is None else nt_valid, jnp.int32)
         fn = _fused_program_fn(
-            full_cov, n_streams, backend, update_dtype, None, None,
+            head, n_streams, backend, update_dtype, None, None,
             batch_dispatch, mesh, kernel,
         )
         _record_program(
-            "run_program", xc, xtc.shape[-3], full_cov, n_streams, backend
+            "run_program", xc, xtc.shape[-3], head, n_streams, backend
         )
+        _count_head(head, xc)
         with _tracer.span("fused_batched"):
             env = fn(xc, yc, xtc, params, nv, ntv)
     else:
         fn = _fused_program_fn(
-            full_cov, n_streams, backend, update_dtype, n, nh, batch_dispatch,
+            head, n_streams, backend, update_dtype, n, nh, batch_dispatch,
             mesh, kernel,
         )
         _record_program(
-            "run_program", xc, xtc.shape[-3], full_cov, n_streams, backend
+            "run_program", xc, xtc.shape[-3], head, n_streams, backend
         )
+        _count_head(head, xc)
         with _tracer.span("fused_batched"):
             env = fn(xc, yc, xtc, params)
     with _tracer.span("untile"):
         mean = env["mean"].reshape(b, -1)[:, :nh]
-        if full_cov:
+        if head == Head.COVARIANCE:
             q_tiles = xtc.shape[1]
             sigma_tiles = env["prior"].reshape(b, q_tiles, q_tiles, m, m)
             result = (mean, tiling.untile_dense(sigma_tiles)[:, :nh, :nh])
+        elif head == Head.VARIANCE:
+            result = (mean, env["prior"].reshape(b, -1)[:, :nh])
         else:
             result = mean
     if not with_state:
@@ -605,7 +670,7 @@ def predict_from_state_batched(
     state: PosteriorState,
     x_test: jax.Array,
     *,
-    full_cov: bool = False,
+    head: Head = Head.NONE,
     n_streams: Optional[int] = None,
     dtype=None,
     nt_valid=None,
@@ -615,8 +680,8 @@ def predict_from_state_batched(
 
     The state holds B factors/weights (leading B axis); x_test (B, n̂, D).
     Reuses the cached O(n^3) work and runs only the cross-covariance / mean
-    (and optionally the matrix-solve tail) — all through the batched
-    executor plans.  Assembly uses the jnp tile kernel (per-problem params).
+    (and, for an uncertainty ``head``, the matrix-solve tail) — all through
+    the batched executor plans.  Assembly uses the jnp tile kernel (per-problem params).
 
     Ragged states (``state.n_valid`` set) mask the cross covariance at each
     problem's own frontier — required for correctness, not just economy:
@@ -628,8 +693,10 @@ def predict_from_state_batched(
     params = state.params
     kernel = state.kernel
     b, nh = x_test.shape[0], x_test.shape[1]
+    head = Head(head)
     if obs.enabled() and not isinstance(x_test, jax.core.Tracer):
         obs.inc("predict.warm_tail_batched")
+    _count_head(head, x_test)
     dtype = state.x_chunks.dtype if dtype is None else jnp.dtype(dtype)
     # the warm tail runs op-by-op (no enclosing jit): committing the test
     # block to the fleet layout is enough — the cached state buffers carry
@@ -644,7 +711,7 @@ def predict_from_state_batched(
         xtc, state.x_chunks, params, ntv, nv, kernel
     )
     mean = triangular.tiled_matvec(kstar, state.alpha).reshape(b, -1)[:, :nh]
-    if not full_cov:
+    if head == Head.NONE:
         return mean
 
     # L V = K_{X,X̂}:  B tiles are the per-problem transpose grids of K_*.
@@ -652,6 +719,11 @@ def predict_from_state_batched(
     v = triangular.forward_substitution_matrix(
         state.lpacked, b_tiles, n_streams=n_streams
     )
+    if head == Head.VARIANCE:
+        prior = assemble_prior_diagonal_batched(xtc, params, ntv, kernel)
+        var = prior - triangular.tiled_gram_diag(v)      # (B, Q, mq)
+        with _tracer.span("untile"):
+            return mean, var.reshape(b, -1)[:, :nh]
     w = triangular.tiled_gram(v)                         # (B, Q, Q, mq, mq)
     prior = assemble_prior_tiles_batched(xtc, params, ntv, kernel)
     sigma_tiles = prior - w
@@ -709,18 +781,18 @@ def nlml_program_env(
             mesh = None  # unbatched programs have no problem axis to shard
     if n_valid is not None:
         fn = _fused_program_fn(
-            False, n_streams, backend, update_dtype, None, None,
+            Head.NONE, n_streams, backend, update_dtype, None, None,
             batch_dispatch, mesh, kernel,
         )
         nv = jnp.asarray(n_valid, jnp.int32)
-        _record_program("run_program", xc, 0, False, n_streams, backend)
+        _record_program("run_program", xc, 0, Head.NONE, n_streams, backend)
         with _tracer.span("nlml_program"):
             return fn(xc, yc, xtc, params, nv, jnp.asarray(0, jnp.int32)), yc
     fn = _fused_program_fn(
-        False, n_streams, backend, update_dtype, n, 0, batch_dispatch, mesh,
+        Head.NONE, n_streams, backend, update_dtype, n, 0, batch_dispatch, mesh,
         kernel,
     )
-    _record_program("run_program", xc, 0, False, n_streams, backend)
+    _record_program("run_program", xc, 0, Head.NONE, n_streams, backend)
     with _tracer.span("nlml_program"):
         return fn(xc, yc, xtc, params), yc
 
@@ -757,7 +829,7 @@ def predict(
         x_test,
         params,
         m,
-        full_cov=full_cov,
+        head=Head.COVARIANCE if full_cov else Head.NONE,
         n_streams=n_streams,
         backend=backend,
         update_dtype=update_dtype,
@@ -797,7 +869,7 @@ def predict_staged(
     return predict_from_state(
         state,
         x_test,
-        full_cov=full_cov,
+        head=Head.COVARIANCE if full_cov else Head.NONE,
         n_streams=n_streams,
         backend=backend,
         dtype=dtype,

@@ -31,7 +31,7 @@ pending one.  They level-schedule the same way the factorization does.
 
 Finally, :func:`build_program_schedule` fuses the *entire* prediction
 pipeline — covariance assembly, Cholesky, both substitutions, cross
-covariance, predictive mean and (optionally) the full-covariance tail — into
+covariance, predictive mean and (optionally) an uncertainty head — into
 one DAG with cross-stage edges, so e.g. ``TRSV(0)`` depends only on
 ``POTRF@col0`` and cross-covariance tiles are ready at level 0.  The
 wavefront scheduler then co-batches solve rows and cross-assembly into the
@@ -42,6 +42,7 @@ tail of Cholesky columns exactly like the paper's Fig. 5 timeline (DESIGN.md
 from __future__ import annotations
 
 import dataclasses
+import enum
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 # Task encodings: (op, i, j, k).  k is only used by GEMM.
@@ -68,7 +69,24 @@ XGEMV = "xgemv"          # predictive-mean row p: mean_p = sum_q K_*[p,q] alpha_
 VINIT = "vinit"          # uncertainty workspace row i: V_i,q <- K_*[q,i]^T
 VTRSV = "vtrsv"          # matrix forward solve, diagonal tile of row i
 VGEMV = "vgemv"          # matrix forward propagation V_i -= L_ij V_j
-GRAM = "gram"            # Sigma = prior - V^T V (single closing task)
+GRAM = "gram"            # prior - V^T V, or its diagonal (single closing task)
+
+
+class Head(enum.IntEnum):
+    """The fused program's closing uncertainty head (DESIGN.md §7).
+
+    ``NONE``: the mean alone (and the NLML prefix, ``q_tiles=0``).
+    ``COVARIANCE``: Sigma = prior - V^T V over all Q^2 test tiles.
+    ``VARIANCE``: diag(Sigma) alone — the Q diagonal prior tiles' diagonals
+    less the column sums of V squared; no Q x Q buffer exists.  The values
+    keep the old ``uncertainty`` flag's meaning: False is ``NONE``, True is
+    ``COVARIANCE``.
+    """
+
+    NONE = 0
+    COVARIANCE = 1
+    VARIANCE = 2
+
 
 # Streaming-update ops (DESIGN.md §10).  Two DAG families:
 #
@@ -185,7 +203,7 @@ class Schedule:
     levels: Tuple[Tuple[Task, ...], ...]
     kind: str = "cholesky"  # "cholesky" | "forward" | "backward" | "program"
     q_tiles: int = 0        # test tile count (program schedules only)
-    uncertainty: bool = False  # program includes the full-covariance tail
+    head: Head = Head.NONE  # the program's uncertainty head
 
     @property
     def critical_path(self) -> int:
@@ -293,13 +311,25 @@ def build_solve_schedule(m_tiles: int, *, lower: bool = True) -> Schedule:
 # ---------------------------------------------------------------------------
 
 
-def program_tasks(m_tiles: int, q_tiles: int, *, uncertainty: bool = False) -> List[Task]:
+def _prior_tiles(q_tiles: int, head: Head) -> List[Tuple[int, int]]:
+    """The prior tiles a head reads: all Q^2, or the Q diagonal ones."""
+    if head == Head.COVARIANCE:
+        return [(p, q) for p in range(q_tiles) for q in range(q_tiles)]
+    if head == Head.VARIANCE:
+        return [(p, p) for p in range(q_tiles)]
+    return []
+
+
+def program_tasks(m_tiles: int, q_tiles: int, *, head: Head = Head.NONE) -> List[Task]:
     """Every task of the fused prediction pipeline, in program order.
 
     The order is topological for :func:`program_deps` (assembly first, then
     factorization, forward substitution, backward substitution, prediction
-    heads), which is what ``_asap_levels`` requires.
+    heads), which is what ``_asap_levels`` requires.  Both uncertainty heads
+    run the same matrix solve; they differ in the PRIOR tiles they assemble
+    and in what the closing GRAM computes.
     """
+    head = Head(head)
     tasks: List[Task] = []
     for j in range(m_tiles):
         for i in range(j, m_tiles):
@@ -307,17 +337,15 @@ def program_tasks(m_tiles: int, q_tiles: int, *, uncertainty: bool = False) -> L
     for p in range(q_tiles):
         for q in range(m_tiles):
             tasks.append((CROSS, p, q, -1))
-    if uncertainty:
-        for p in range(q_tiles):
-            for q in range(q_tiles):
-                tasks.append((PRIOR, p, q, -1))
+    for p, q in _prior_tiles(q_tiles, head):
+        tasks.append((PRIOR, p, q, -1))
     tasks += all_tasks(m_tiles)
     tasks += solve_tasks(m_tiles, lower=True)  # forward: TRSV / GEMV
     for op, i, j, k in solve_tasks(m_tiles, lower=False):
         tasks.append((TRSV_B if op == TRSV else GEMV_B, i, j, k))
     for p in range(q_tiles):
         tasks.append((XGEMV, p, -1, -1))
-    if uncertainty:
+    if head != Head.NONE:
         for i in range(m_tiles):
             tasks.append((VINIT, i, -1, -1))
         for op, i, j, k in solve_tasks(m_tiles, lower=True):
@@ -326,7 +354,9 @@ def program_tasks(m_tiles: int, q_tiles: int, *, uncertainty: bool = False) -> L
     return tasks
 
 
-def program_deps(task: Task, m_tiles: int, q_tiles: int) -> List[Task]:
+def program_deps(
+    task: Task, m_tiles: int, q_tiles: int, head: Head = Head.NONE
+) -> List[Task]:
     """Direct dependencies of a task in the fused prediction program.
 
     These are the paper's cross-stage dataflow edges: a consumer waits only
@@ -341,6 +371,9 @@ def program_deps(task: Task, m_tiles: int, q_tiles: int) -> List[Task]:
     diagonal solve also publishes the row into the separate ``alpha`` buffer
     that the backward pass accumulates in, so backward writes can never
     clobber rows a forward GEMV still reads (no WAR anti-edges needed).
+
+    ``head`` matters only to GRAM, which waits for the PRIOR tiles its head
+    assembles (:func:`program_tasks`).
     """
     op, i, j, k = task
     m = m_tiles
@@ -402,13 +435,13 @@ def program_deps(task: Task, m_tiles: int, q_tiles: int) -> List[Task]:
         return deps
     if op == GRAM:
         return [(VTRSV, r, r, -1) for r in range(m)] + [
-            (PRIOR, p, q, -1) for p in range(q_tiles) for q in range(q_tiles)
+            (PRIOR, p, q, -1) for p, q in _prior_tiles(q_tiles, Head(head))
         ]
     raise ValueError(op)
 
 
 def build_program_schedule(
-    m_tiles: int, q_tiles: int, *, uncertainty: bool = False
+    m_tiles: int, q_tiles: int, *, head: Head = Head.NONE
 ) -> Schedule:
     """ASAP level schedule of the fused prediction program.
 
@@ -416,14 +449,15 @@ def build_program_schedule(
     next to the TRSM panel of column 0, and every ``CROSS`` tile sits at
     level 0 alongside the covariance assembly.
     """
-    tasks = program_tasks(m_tiles, q_tiles, uncertainty=uncertainty)
-    levels = _asap_levels(tasks, lambda t: program_deps(t, m_tiles, q_tiles))
+    head = Head(head)
+    tasks = program_tasks(m_tiles, q_tiles, head=head)
+    levels = _asap_levels(tasks, lambda t: program_deps(t, m_tiles, q_tiles, head))
     return Schedule(
         m_tiles=m_tiles,
         levels=levels,
         kind="program",
         q_tiles=q_tiles,
-        uncertainty=uncertainty,
+        head=head,
     )
 
 
@@ -438,7 +472,7 @@ def build_nlml_schedule(m_tiles: int) -> Schedule:
     prediction heads.  This is the forward program that
     :func:`repro.core.mll.nlml_tiled` differentiates.
     """
-    return build_program_schedule(m_tiles, 0, uncertainty=False)
+    return build_program_schedule(m_tiles, 0, head=Head.NONE)
 
 
 # ---------------------------------------------------------------------------
@@ -565,7 +599,7 @@ def task_deps(task: Task, schedule: Schedule) -> List[Task]:
     if schedule.kind == "cholesky":
         return _deps(task, schedule.m_tiles)
     if schedule.kind == "program":
-        return program_deps(task, schedule.m_tiles, schedule.q_tiles)
+        return program_deps(task, schedule.m_tiles, schedule.q_tiles, schedule.head)
     if schedule.kind == "update_append":
         return append_deps(task, schedule.m_tiles)
     if schedule.kind == "update_rank":
@@ -575,7 +609,7 @@ def task_deps(task: Task, schedule: Schedule) -> List[Task]:
     return solve_deps(task, schedule.m_tiles, lower=schedule.kind == "forward")
 
 
-def _dag(m_tiles: int, kind: str, q_tiles: int = 0, uncertainty: bool = False):
+def _dag(m_tiles: int, kind: str, q_tiles: int = 0, head: Head = Head.NONE):
     """(tasks in topological order, deps_fn) for a DAG family."""
     if kind == "cholesky":
         return all_tasks(m_tiles), lambda t: _deps(t, m_tiles)
@@ -587,8 +621,8 @@ def _dag(m_tiles: int, kind: str, q_tiles: int = 0, uncertainty: bool = False):
         )
     if kind == "program":
         return (
-            program_tasks(m_tiles, q_tiles, uncertainty=uncertainty),
-            lambda t: program_deps(t, m_tiles, q_tiles),
+            program_tasks(m_tiles, q_tiles, head=head),
+            lambda t: program_deps(t, m_tiles, q_tiles, head),
         )
     if kind == "update_append":
         return append_tasks(m_tiles), lambda t: append_deps(t, m_tiles)
@@ -615,7 +649,7 @@ def build_wavefront_schedule(
     *,
     kind: str = "cholesky",
     q_tiles: int = 0,
-    uncertainty: bool = False,
+    head: Head = Head.NONE,
 ) -> Schedule:
     """Finite-stream-pool list schedule: the paper's round-robin pool, static.
 
@@ -645,7 +679,8 @@ def build_wavefront_schedule(
 
     if n_streams < 1:
         raise ValueError(f"n_streams must be >= 1 or None, got {n_streams}")
-    tasks, deps_fn = _dag(m_tiles, kind, q_tiles, uncertainty)
+    head = Head(head)
+    tasks, deps_fn = _dag(m_tiles, kind, q_tiles, head)
     bottom = _bottom_levels(tasks, deps_fn)
     order = {t: i for i, t in enumerate(tasks)}
     indeg = {t: len(deps_fn(t)) for t in tasks}
@@ -694,7 +729,7 @@ def build_wavefront_schedule(
         levels=tuple(waves),
         kind=kind,
         q_tiles=q_tiles,
-        uncertainty=uncertainty,
+        head=head,
     )
 
 

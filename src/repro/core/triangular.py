@@ -114,6 +114,15 @@ def tiled_gram(v_tiles: jax.Array) -> jax.Array:
     return jnp.einsum("ipab,iqac->pqbc", v_tiles, v_tiles)
 
 
+def tiled_gram_diag(v_tiles: jax.Array) -> jax.Array:
+    """diag(V^T V) for V tiles (M, Q, m, mq) -> chunks (Q, mq): the column
+    sums of V squared, element-wise — the variance head's O(n n̂) gram.
+
+    Batched: (B, M, Q, m, mq) -> (B, Q, mq).
+    """
+    return jnp.sum(jnp.square(v_tiles), axis=(-4, -2))
+
+
 def packed_matvec(
     lpacked: jax.Array, chunks: jax.Array, *, transpose: bool = False
 ) -> jax.Array:

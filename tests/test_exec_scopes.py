@@ -15,6 +15,7 @@ import pytest
 
 from repro.core import executor, tiling
 from repro.core.kernels_math import SEKernelParams
+from repro.core.scheduler import Head
 
 M_TILES, Q_TILES, TILE, D = 4, 2, 128, 3
 PARAMS = SEKernelParams(lengthscale=1.0, vertical=1.0, noise=0.1)
@@ -34,10 +35,10 @@ def _inputs():
             tiling.pad_features(xt, TILE), n, nh)
 
 
-def _compiled_text() -> str:
+def _compiled_text(head=Head.COVARIANCE) -> str:
     xc, yc, xtc, n, nh = _inputs()
     fn = jax.jit(lambda a, b, c: executor.run_program(
-        a, b, c, PARAMS, n, nh, uncertainty=True))
+        a, b, c, PARAMS, n, nh, head=head))
     return fn.lower(xc, yc, xtc).compile().as_text()
 
 
@@ -80,3 +81,17 @@ def test_scopes_change_metadata_only(compiled, monkeypatch):
     plain = _compiled_text()
     assert "repro.exec." not in plain
     assert _without_metadata(plain) == _without_metadata(compiled)
+
+
+def test_variance_head_names_gram_and_prior():
+    # the variance head keeps the op names ``gram`` and ``prior``, so the
+    # stage map that reads the covariance head's trace reads this one too
+    compiled = _compiled_text(Head.VARIANCE)
+    plan = executor.program_plan(M_TILES, Q_TILES, Head.VARIANCE, None)
+    families = {bt.op for level in plan.levels for bt in level}
+    assert families == {bt.op for level in executor.program_plan(
+        M_TILES, Q_TILES, Head.COVARIANCE, None).levels for bt in level}
+    assert set(_SCOPE.findall(compiled)) == families
+    for family in ("gram", "prior"):
+        assert any(f"repro.exec.{family}" in line and opcode not in ("parameter", "constant")
+                   for opcode, line in _instructions(compiled)), family

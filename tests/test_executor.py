@@ -211,14 +211,14 @@ def test_fused_state_slice_matches_staged_state(rng):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("uncertainty", [False, True])
+@pytest.mark.parametrize("uncertainty", [False, True, pytest.param(sch.Head.VARIANCE, id="variance")])
 @pytest.mark.parametrize("m_tiles", [8, 12, 16])
 def test_fused_program_fewer_launches(m_tiles, uncertainty):
     q_tiles = max(m_tiles // 4, 1)
     for ns in (None, 4, 16):
         fused = executor.program_plan(m_tiles, q_tiles, uncertainty, ns).n_batches
         staged = executor.staged_launch_count(
-            m_tiles, uncertainty=uncertainty, n_streams=ns
+            m_tiles, head=uncertainty, n_streams=ns
         )
         assert fused < staged, (m_tiles, uncertainty, ns, fused, staged)
     # n_streams=1 is the fully sequential baseline: one task per launch
@@ -227,24 +227,45 @@ def test_fused_program_fewer_launches(m_tiles, uncertainty):
     for ns in (1, 8):
         fused = executor.program_plan(m_tiles, q_tiles, uncertainty, ns).n_batches
         staged = executor.staged_launch_count(
-            m_tiles, uncertainty=uncertainty, n_streams=ns
+            m_tiles, head=uncertainty, n_streams=ns
         )
         assert fused <= staged, (m_tiles, uncertainty, ns, fused, staged)
 
 
-@pytest.mark.parametrize("uncertainty", [False, True])
+@pytest.mark.parametrize("uncertainty", [False, True, pytest.param(sch.Head.VARIANCE, id="variance")])
 @pytest.mark.parametrize("n_streams", [None, 1, 4])
 def test_program_plan_covers_dag(uncertainty, n_streams):
     m_tiles, q_tiles = 6, 2
     plan = executor.program_plan(m_tiles, q_tiles, uncertainty, n_streams)
-    tasks = sch.program_tasks(m_tiles, q_tiles, uncertainty=uncertainty)
+    tasks = sch.program_tasks(m_tiles, q_tiles, head=uncertainty)
     assert sorted(plan.flat_tasks()) == sorted(tasks)
     level_of = {
         t: li for li, lvl in enumerate(plan.levels) for b in lvl for t in b.tasks
     }
     for t in tasks:
-        for d in sch.program_deps(t, m_tiles, q_tiles):
+        for d in sch.program_deps(t, m_tiles, q_tiles, uncertainty):
             assert level_of[d] < level_of[t], (t, d)
+
+
+# Launches and waves of the parent's program plans at M = 8, Q = 4, pinned per
+# n_streams: the variance head leaves the covariance head's plan as it was.
+_PINNED_PLANS = {  # n_streams: (covariance batches, waves; mean-only batches)
+    None: (73, 40, 55), 1: (234, 230, 195), 2: (152, 117, 123),
+    4: (120, 61, 92), 8: (99, 41, 78), 16: (79, 40, 60),
+}
+
+
+@pytest.mark.parametrize("n_streams", list(_PINNED_PLANS))
+def test_program_plan_counts_pinned(n_streams):
+    cov_batches, cov_waves, none_batches = _PINNED_PLANS[n_streams]
+    cov = executor.program_plan(8, 4, sch.Head.COVARIANCE, n_streams)
+    assert (cov.n_batches, len(cov.levels)) == (cov_batches, cov_waves)
+    assert executor.program_plan(8, 4, True, n_streams) is cov  # the old flag
+    assert executor.program_plan(8, 4, sch.Head.NONE, n_streams).n_batches == none_batches
+    # the variance head launches as often: one diagonal PRIOR batch where the
+    # covariance head has its full grid, the same single GRAM
+    var = executor.program_plan(8, 4, sch.Head.VARIANCE, n_streams)
+    assert var.n_batches == cov_batches
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ from repro.core import executor, mll, tiling, triangular
 from repro.core import predict as pred
 from repro.core import update as upd
 from repro.core.kernels_math import SEKernelParams
+from repro.core.scheduler import Head
 
 M = 32
 NS_MIX = (48, 64, 200)            # spans <1 tile slack, exact fit, 7 tiles
@@ -53,14 +54,14 @@ def test_ragged_fused_matches_per_problem_loop(rng, backend, n_streams):
 
     atol_m, atol_s = (3e-4, 3e-3) if backend == "jnp" else (5e-4, 5e-3)
     (mean, sigma), state = pred.predict_fused_batched(
-        xst, yst, xtb, PARAMS, M, full_cov=True, n_streams=n_streams,
+        xst, yst, xtb, PARAMS, M, head=Head.COVARIANCE, n_streams=n_streams,
         backend=backend, with_state=True, n_valid=nv,
     )
     nlml = mll.nlml_from_state(state, yst)
     for i, (x, y) in enumerate(zip(xs, ys)):
         mr, sr = pred.predict_fused(
             jnp.asarray(x), jnp.asarray(y), jnp.asarray(xt), PARAMS, M,
-            full_cov=True, n_streams=n_streams, backend=backend,
+            head=Head.COVARIANCE, n_streams=n_streams, backend=backend,
         )
         np.testing.assert_allclose(np.asarray(mean[i]), np.asarray(mr), atol=atol_m)
         np.testing.assert_allclose(np.asarray(sigma[i]), np.asarray(sr), atol=atol_s)
@@ -109,8 +110,8 @@ def test_ragged_nt_valid_masks_test_rows(rng):
 
 def test_ragged_plan_and_trace_reuse(rng):
     # the ragged program fn is ONE lru-cached object regardless of frontiers
-    fn_a = pred._fused_program_fn(False, None, "jnp", None, None, None, "flat")
-    fn_b = pred._fused_program_fn(False, None, "jnp", None, None, None, "flat")
+    fn_a = pred._fused_program_fn(Head.NONE, None, "jnp", None, None, None, "flat")
+    fn_b = pred._fused_program_fn(Head.NONE, None, "jnp", None, None, None, "flat")
     assert fn_a is fn_b
 
     cap = 4 * M

@@ -99,32 +99,34 @@ def test_solve_levels_are_antichains(lower):
 # ---------------------------------------------------------------------------
 
 
-def _program_task_count(m, q, uncertainty):
+def _program_task_count(m, q, head):
     chol = sum(sch.theoretical_task_counts(m).values())
     solve = m + m * (m - 1) // 2
     count = m * (m + 1) // 2 + q * m + chol + 2 * solve + q  # +cross +xgemv
-    if uncertainty:
-        count += q * q + m + solve + 1  # prior + vinit + matrix solve + gram
+    if head != sch.Head.NONE:
+        count += m + solve + 1  # vinit + matrix solve + gram
+    # prior tiles: the whole Q x Q grid, or its diagonal for the variance
+    count += {sch.Head.NONE: 0, sch.Head.COVARIANCE: q * q, sch.Head.VARIANCE: q}[head]
     return count
 
 
-@pytest.mark.parametrize("uncertainty", [False, True])
+@pytest.mark.parametrize("uncertainty", [False, True, pytest.param(sch.Head.VARIANCE, id="variance")])
 @pytest.mark.parametrize("m,q", [(1, 1), (4, 1), (8, 2)])
 def test_program_task_counts(m, q, uncertainty):
-    tasks = sch.program_tasks(m, q, uncertainty=uncertainty)
+    tasks = sch.program_tasks(m, q, head=uncertainty)
     assert len(tasks) == len(set(tasks)) == _program_task_count(m, q, uncertainty)
-    s = sch.build_program_schedule(m, q, uncertainty=uncertainty)
+    s = sch.build_program_schedule(m, q, head=uncertainty)
     assert s.n_tasks == len(tasks)
 
 
-@pytest.mark.parametrize("uncertainty", [False, True])
+@pytest.mark.parametrize("uncertainty", [False, True, pytest.param(sch.Head.VARIANCE, id="variance")])
 def test_program_deps_respect_level_order(uncertainty):
     m, q = 6, 2
-    s = sch.build_program_schedule(m, q, uncertainty=uncertainty)
+    s = sch.build_program_schedule(m, q, head=uncertainty)
     level_of = {t: i for i, lvl in enumerate(s.levels) for t in lvl}
     assert len(level_of) == s.n_tasks
     for t, lv in level_of.items():
-        for d in sch.program_deps(t, m, q):
+        for d in sch.program_deps(t, m, q, uncertainty):
             assert level_of[d] < lv, (t, d)
 
 
@@ -141,7 +143,7 @@ def test_program_cross_stage_edges():
     assert level_of[(sch.CROSS, 0, 0, -1)] == 0
 
 
-@pytest.mark.parametrize("uncertainty", [False, True])
+@pytest.mark.parametrize("uncertainty", [False, True, pytest.param(sch.Head.VARIANCE, id="variance")])
 @pytest.mark.parametrize("m", [4, 8])
 def test_program_wavefront_mixes_stages(m, uncertainty):
     """Acceptance: for M >= 4 the fused wavefront has at least one wave
@@ -152,7 +154,7 @@ def test_program_wavefront_mixes_stages(m, uncertainty):
         sch.CROSS, sch.VINIT, sch.VTRSV, sch.VGEMV,
     }
     s = sch.build_wavefront_schedule(
-        m, 4, kind="program", q_tiles=2, uncertainty=uncertainty
+        m, 4, kind="program", q_tiles=2, head=uncertainty
     )
     mixed = [
         lvl for lvl in s.levels
@@ -167,13 +169,58 @@ def test_program_waves_are_antichains(n_streams):
     op-affinity packing."""
     m, q = 5, 2
     s = sch.build_wavefront_schedule(
-        m, n_streams, kind="program", q_tiles=q, uncertainty=True
+        m, n_streams, kind="program", q_tiles=q, head=sch.Head.COVARIANCE
     )
     for level in s.levels:
         level_set = set(level)
         for t in level:
-            for d in sch.program_deps(t, m, q):
+            for d in sch.program_deps(t, m, q, sch.Head.COVARIANCE):
                 assert d not in level_set, (t, d)
+
+
+# The variance head: Q diagonal prior tiles and one closing gram, on the same
+# matrix solve as the covariance head.
+@pytest.mark.parametrize("m,q", [(1, 1), (4, 1), (8, 4)])
+def test_variance_head_tasks(m, q):
+    var = sch.program_tasks(m, q, head=sch.Head.VARIANCE)
+    cov = sch.program_tasks(m, q, head=sch.Head.COVARIANCE)
+    priors = [t for t in var if t[0] == sch.PRIOR]
+    assert priors == [(sch.PRIOR, p, p, -1) for p in range(q)]
+    assert [t for t in var if t[0] == sch.GRAM] == [(sch.GRAM, -1, -1, -1)]
+    # everything but the prior grid is the covariance head's, in its order
+    assert [t for t in var if t[0] != sch.PRIOR] == [t for t in cov if t[0] != sch.PRIOR]
+    assert sch.program_deps((sch.GRAM, -1, -1, -1), m, q, sch.Head.VARIANCE) == [
+        (sch.VTRSV, r, r, -1) for r in range(m)
+    ] + priors
+
+
+def test_heads_keep_the_old_flag_meaning():
+    """False and True read as the old ``uncertainty`` flag did."""
+    assert sch.Head(False) == sch.Head.NONE
+    assert sch.Head(True) == sch.Head.COVARIANCE
+    assert sch.program_tasks(5, 2, head=True) == sch.program_tasks(5, 2, head=sch.Head.COVARIANCE)
+    assert sch.build_nlml_schedule(4).head == sch.Head.NONE
+
+
+# Task counts of the parent program at M = 8, Q = 4, pinned: the covariance
+# head and the mean-only program are unchanged by the variance head.
+_PINNED_OP_COUNTS = {
+    "assemble": 36, "cross": 32, "gemm": 56, "gemv": 28, "gemv_b": 28, "gram": 1,
+    "potrf": 8, "prior": 16, "syrk": 28, "trsm": 28, "trsv": 8, "trsv_b": 8,
+    "vgemv": 28, "vinit": 8, "vtrsv": 8, "xgemv": 4,
+}
+
+
+@pytest.mark.parametrize("head,n_tasks", [(sch.Head.NONE, 264), (sch.Head.COVARIANCE, 325),
+                                          (sch.Head.VARIANCE, 313)])
+def test_program_task_counts_pinned(head, n_tasks):
+    tasks = sch.program_tasks(8, 4, head=head)
+    assert len(tasks) == n_tasks
+    counts = sch.build_program_schedule(8, 4, head=head).op_counts()
+    if head == sch.Head.COVARIANCE:
+        assert counts == _PINNED_OP_COUNTS
+    if head == sch.Head.VARIANCE:
+        assert counts == {**_PINNED_OP_COUNTS, "prior": 4}
 
 
 # ---------------------------------------------------------------------------

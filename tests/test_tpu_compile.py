@@ -20,6 +20,7 @@ from repro.core import executor
 from repro.core import kernels_math as km
 from repro.core import mll
 from repro.core import predict as pred
+from repro.core.scheduler import Head
 from repro.kernels.trailing_update import trailing_update
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -58,7 +59,8 @@ def _fits(compiled):
 
 
 def test_fused_predict_compiles(one_chip):
-    fn = pred._fused_program_fn(True, None, "jnp", None, N, N, kernel=km.resolve_kernel(None))
+    fn = pred._fused_program_fn(Head.COVARIANCE, None, "jnp", None, N, N,
+                                kernel=km.resolve_kernel(None))
     m_tiles = N // TILE
     compiled = fn.lower(
         _spec(one_chip, (m_tiles, TILE, D)),
@@ -72,7 +74,8 @@ def test_fused_predict_compiles(one_chip):
 def test_fused_predict_ops_carry_their_family(one_chip):
     # every op the chip runs that came from the executor's plan names its op
     # family (``repro.exec.<op>``): the profiler trace reads it as ``tf_op``
-    fn = pred._fused_program_fn(True, None, "jnp", None, N, N, kernel=km.resolve_kernel(None))
+    fn = pred._fused_program_fn(Head.COVARIANCE, None, "jnp", None, N, N,
+                                kernel=km.resolve_kernel(None))
     m_tiles = N // TILE
     text = fn.lower(
         _spec(one_chip, (m_tiles, TILE, D)),
@@ -80,7 +83,7 @@ def test_fused_predict_ops_carry_their_family(one_chip):
         _spec(one_chip, (m_tiles, TILE, D)),
         _params(one_chip),
     ).compile().as_text()
-    plan = executor.program_plan(m_tiles, m_tiles, True, None)
+    plan = executor.program_plan(m_tiles, m_tiles, Head.COVARIANCE, None)
     families = {bt.op for level in plan.levels for bt in level}
     assert set(re.findall(r"repro\.exec\.(\w+)", text)) == families
     work = [line for line in text.splitlines()
@@ -88,6 +91,29 @@ def test_fused_predict_ops_carry_their_family(one_chip):
             and "op_name=" in line]
     assert len(work) > 20
     assert all("repro.exec." in line for line in work)
+
+
+def test_fused_variance_predict_compiles_smaller(one_chip):
+    # the variance head holds no n̂ x n̂ buffer, so it needs less device
+    # memory than the covariance head of the same size; it carries every
+    # op family of its plan
+    m_tiles = N // TILE
+    args = (_spec(one_chip, (m_tiles, TILE, D)), _spec(one_chip, (m_tiles, TILE)),
+            _spec(one_chip, (m_tiles, TILE, D)), _params(one_chip))
+    used = {}
+    for head in (Head.VARIANCE, Head.COVARIANCE):
+        fn = pred._fused_program_fn(head, None, "jnp", None, N, N,
+                                    kernel=km.resolve_kernel(None))
+        compiled = fn.lower(*args).compile()
+        assert _fits(compiled)
+        ma = compiled.memory_analysis()
+        used[head] = ma.temp_size_in_bytes + ma.output_size_in_bytes
+        if head == Head.VARIANCE:
+            plan = executor.program_plan(m_tiles, m_tiles, head, None)
+            families = {bt.op for level in plan.levels for bt in level}
+            assert set(re.findall(r"repro\.exec\.(\w+)", compiled.as_text())) == families
+    # at least the n̂ x n̂ f32 covariance less the n̂ variances
+    assert used[Head.COVARIANCE] - used[Head.VARIANCE] >= (N * N - N) * 4
 
 
 def test_tiled_nlml_grad_compiles(one_chip):

@@ -16,6 +16,7 @@ from repro import compat
 from repro.core import GaussianProcess, GPBatch, SEKernelParams
 from repro.core import executor, scheduler, tiling, triangular, update
 from repro.core import predict as pred
+from repro.core.scheduler import Head
 
 PARAMS = SEKernelParams.paper_defaults()
 
@@ -118,8 +119,8 @@ def test_extend_matches_scratch(rng, n0, b, backend):
         np.asarray(grown.alpha), np.asarray(ref.alpha), rtol=1e-3, atol=1e-4
     )
     xt = rng.standard_normal((7, x.shape[1])).astype(np.float32)
-    mu, cov = pred.predict_from_state(grown, jnp.asarray(xt), full_cov=True)
-    mu_r, cov_r = pred.predict_from_state(ref, jnp.asarray(xt), full_cov=True)
+    mu, cov = pred.predict_from_state(grown, jnp.asarray(xt), head=Head.COVARIANCE)
+    mu_r, cov_r = pred.predict_from_state(ref, jnp.asarray(xt), head=Head.COVARIANCE)
     np.testing.assert_allclose(np.asarray(mu), np.asarray(mu_r), atol=1e-4)
     np.testing.assert_allclose(np.asarray(cov), np.asarray(cov_r), atol=1e-4)
 
