@@ -3,6 +3,7 @@
 from repro.core.gp import GaussianProcess, GPBatch, GPFleet
 from repro.core.kernels_math import (
     ARDKernelParams,
+    ARDMatern52,
     ARDSquaredExponential,
     Kernel,
     Matern12,
@@ -37,6 +38,7 @@ __all__ = [
     "Matern52",
     "RationalQuadratic",
     "ARDSquaredExponential",
+    "ARDMatern52",
     "White",
     "Sum",
     "Product",

@@ -31,6 +31,8 @@ single GP, every launch B times wider.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import sys
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -1052,6 +1054,56 @@ class GPBatch:
         return x_test
 
 
+# The device's memory over this many times M x (env bytes) of a problem is a
+# cold launch's width.  The TPU compile of the ragged covariance-head program
+# at tile 128 (8-225 problems, caps 4-16) holds 0.5-2.1 x M x the env buffers
+# (packed factor, cross, V and prior tiles) a problem, and 2.8 x where it is
+# given more memory than it needs (DESIGN.md §11): at the worst factor a
+# launch takes 2.8 / 8 = 35% of the memory, the rest holding the fleet's
+# stacked states (one copy: launches write into it) and the answers its
+# callers keep.
+_COLD_ENV_COPIES = 8
+
+
+def _device_memory_limit() -> Optional[int]:
+    """Bytes the default device can hold, where its backend says so."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _cold_launch_width(cap_tiles: int, q_tiles: int, m: int, itemsize: int) -> int:
+    """Problems per launch of a cold bucket program of this geometry: the
+    device's memory over :data:`_COLD_ENV_COPIES` times a problem's share,
+    unbounded where the device reports no limit."""
+    limit = _device_memory_limit()
+    if not limit:
+        return sys.maxsize
+    env_tiles = (tiling.num_packed_tiles(cap_tiles) + 2 * cap_tiles * q_tiles
+                 + q_tiles * q_tiles)
+    per_problem = _COLD_ENV_COPIES * cap_tiles * env_tiles * m * m * itemsize
+    return max(1, int(limit) // per_problem)
+
+
+def _pad_rows(a, pad: int):
+    """``a`` with ``pad`` zero rows appended on its leading (problem) axis."""
+    return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+
+
+@functools.partial(jax.jit, static_argnums=3, donate_argnums=0)
+def _write_rows(buf, part, start, rows):
+    """``buf`` with the first ``rows`` rows of ``part`` written from row
+    ``start``, in place: each launch of a cold bucket lands in one stacked
+    copy, never beside it."""
+    return jax.tree_util.tree_map(
+        lambda b, p: jax.lax.dynamic_update_slice_in_dim(b, p[:rows], start, 0),
+        buf, part)
+
+
+@jax.jit
+def _rows(a):
+    """The rows of a stacked array as separate arrays, in one dispatch."""
+    return tuple(a[i] for i in range(a.shape[0]))
+
+
 @dataclasses.dataclass
 class _Bucket:
     """One bucket of a :class:`GPFleet`: a ragged slice sharing a geometry."""
@@ -1153,6 +1205,7 @@ class GPFleet:
         b = len(xs)
         _validate_fleet_params(self.params, self.kernel, b, "GPFleet")
         self._buckets: Dict[int, _Bucket] = {}
+        self._stacks: Dict[int, tuple] = {}  # cap -> (data key, _stack's arrays)
         self._version = 0
         self._params_bytes = None
 
@@ -1205,71 +1258,136 @@ class GPFleet:
         return km.gather_params(self.params, jnp.asarray(idx), self.kernel)
 
     def _stack(self, idx, cap_tiles):
-        """Zero-pad the bucket's problems to the capacity and stack them."""
+        """Zero-pad the bucket's problems to the capacity and stack them,
+        on the host: one transfer per array, not one pad per problem."""
         capn = cap_tiles * self.tile_size
-        xs = jnp.stack(
-            [jnp.pad(self._xs[i], ((0, capn - self._xs[i].shape[0]), (0, 0)))
-             for i in idx]
-        )
-        ys = jnp.stack(
-            [jnp.pad(self._ys[i], (0, capn - self._ys[i].shape[0]))
-             for i in idx]
-        )
-        nv = jnp.asarray([self._ys[i].shape[0] for i in idx], jnp.int32)
-        return xs, ys, nv
+        dt = np.dtype(self.dtype)
+        xs = np.zeros((len(idx), capn, self._xs[0].shape[-1]), dt)
+        ys = np.zeros((len(idx), capn), dt)
+        hx = jax.device_get([self._xs[i] for i in idx])
+        hy = jax.device_get([self._ys[i] for i in idx])
+        for pos, (x, y) in enumerate(zip(hx, hy)):
+            xs[pos, : len(y)] = x
+            ys[pos, : len(y)] = y
+        nv = np.asarray([len(y) for y in hy], np.int32)
+        return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(nv)
+
+    def _stacked(self, cap_tiles, idx):
+        """The bucket's ``_stack``, kept until the data changes: new
+        hyperparameters re-factor a bucket but never re-stack it."""
+        key = (self._version, tuple(idx), self.tile_size, str(jnp.dtype(self.dtype)))
+        rec = self._stacks.get(cap_tiles)
+        if rec is None or rec[0] != key:
+            with _tracer.span("stack"):
+                rec = (key, self._stack(idx, cap_tiles))
+            self._stacks[cap_tiles] = rec
+        return rec[1]
+
+    def _warm_state(self, cap_tiles, idx):
+        """The bucket's cached state if it is current, else None."""
+        with _tracer.span("bucket"):
+            rec = self._buckets.get(cap_tiles)
+            warm = rec is not None and rec.state is not None \
+                and rec.idx == tuple(idx) and rec.key == self._cache_key()
+        if not warm:
+            return None
+        obs.inc("cache.bucket.warm")
+        return rec.state
 
     def _bucket_state(self, cap_tiles, idx):
         """Warm cached stacked state for one bucket, (re)built cold on miss."""
-        with _tracer.span("bucket"):
-            key = self._cache_key()
-            rec = self._buckets.get(cap_tiles)
-            warm = rec is not None and rec.key == key and rec.idx == tuple(idx) \
-                and rec.state is not None
-        if warm:
-            obs.inc("cache.bucket.warm")
-            return rec.state
+        state = self._warm_state(cap_tiles, idx)
+        if state is not None:
+            return state
+        if self.method != "lowrank":
+            return self._cold_bucket(cap_tiles, idx, None, None, Head.NONE)[1]
         obs.inc("cache.bucket.cold")
-        with _tracer.span("stack"):
-            xs, ys, nv = self._stack(idx, cap_tiles)
+        xs, ys, nv = self._stacked(cap_tiles, idx)
         bp = self._bucket_params(idx)
-        if self.method == "lowrank":
-            ind = self.inducing
-            if ind is not None:
-                ind = jnp.asarray(ind, self.dtype)
-                if ind.ndim == 2:  # one shared set, broadcast over the bucket
-                    ind = jnp.broadcast_to(ind[None], (len(idx),) + ind.shape)
-                else:
-                    ind = ind[jnp.asarray(idx)]
-            state = _lowrank_state_with_retry(
-                lambda jit: lowrank.lowrank_state(
-                    xs, ys, bp, self.m_inducing, self.tile_size,
-                    strategy=self.strategy, inducing=ind,
-                    jitter=jit,
+        ind = self.inducing
+        if ind is not None:
+            ind = jnp.asarray(ind, self.dtype)
+            if ind.ndim == 2:  # one shared set, broadcast over the bucket
+                ind = jnp.broadcast_to(ind[None], (len(idx),) + ind.shape)
+            else:
+                ind = ind[jnp.asarray(idx)]
+        state = _lowrank_state_with_retry(
+            lambda jit: lowrank.lowrank_state(
+                xs, ys, bp, self.m_inducing, self.tile_size,
+                strategy=self.strategy, inducing=ind,
+                jitter=jit,
+                n_streams=self.n_streams, backend=self.op_backend,
+                update_dtype=self.update_dtype, dtype=self.dtype,
+                batch_dispatch=self.batch_dispatch, n_valid=nv,
+                kernel=self.kernel,
+            ),
+            lowrank.DEFAULT_JITTER if self.jitter is None
+            else float(self.jitter),
+        )
+        self._buckets[cap_tiles] = _Bucket(tuple(idx), state, self._cache_key())
+        return state
+
+    def _cold_bucket(self, cap_tiles, idx, xt, ntv, head: Head):
+        """One bucket built cold by the fused ragged program (DESIGN.md §11).
+
+        Answers the bucket's queries ``xt`` (b, n̂, D), ``ntv`` (b,) valid
+        counts, under ``head`` — or none with ``xt=None``, the NLML prefix —
+        and caches the stacked state the program leaves.  The bucket runs as
+        ceil(b / W) launches of one program of width W
+        (:func:`_cold_launch_width`), the last padded with masked problems
+        (``n_valid`` 0); each launch's outputs are written into one stacked
+        copy of the bucket's rows.  Returns ``(result, state)``.
+        """
+        obs.inc("cache.bucket.cold")
+        self._buckets.pop(cap_tiles, None)  # free the stale state first
+        b, m = len(idx), self.tile_size
+        xs, ys, nv = self._stacked(cap_tiles, idx)
+        if xt is None:
+            xt = jnp.zeros((b, 0, xs.shape[-1]), self.dtype)
+            ntv = jnp.zeros((b,), jnp.int32)
+        w = min(b, _cold_launch_width(cap_tiles, -(-xt.shape[1] // m), m,
+                                      jnp.dtype(self.dtype).itemsize))
+        bp = self._bucket_params(idx)
+        sizes = self.sizes
+        out = None  # the launches' answers and state leaves, (b, ...) stacked
+        for s in range(0, b, w):
+            e = min(s + w, b)
+            pad = w - (e - s)
+            rows = lambda a: a[s:e] if pad == 0 else _pad_rows(a[s:e], pad)
+            # masked rows repeat the last problem's hyperparameters
+            p = bp if w == b else self._bucket_params(list(idx[s:e]) + [idx[e - 1]] * pad)
+            with _tracer.span("launch"):
+                result, st = pred.predict_fused_batched(
+                    rows(xs), rows(ys), rows(xt), p, m, head=head,
                     n_streams=self.n_streams, backend=self.op_backend,
                     update_dtype=self.update_dtype, dtype=self.dtype,
-                    batch_dispatch=self.batch_dispatch, n_valid=nv,
+                    with_state=True, batch_dispatch=self.batch_dispatch,
+                    n_valid=rows(nv), nt_valid=rows(ntv), mesh=self.mesh,
                     kernel=self.kernel,
-                ),
-                lowrank.DEFAULT_JITTER if self.jitter is None
-                else float(self.jitter),
-            )
-            self._buckets[cap_tiles] = _Bucket(tuple(idx), state, key)
-            return state
-        env, yc = pred.nlml_program_env(
-            xs, ys, bp, self.tile_size,
-            n_streams=self.n_streams, backend=self.op_backend,
-            update_dtype=self.update_dtype, dtype=self.dtype,
-            batch_dispatch=self.batch_dispatch, n_valid=nv, mesh=self.mesh,
-            kernel=self.kernel,
-        )
+                )
+            part = (result, (st.lpacked, st.alpha, st.x_chunks, st.beta, st.y_chunks))
+            if w == b:
+                out = part
+            else:
+                if out is None:
+                    out = jax.tree_util.tree_map(
+                        lambda a: jnp.zeros((b,) + a.shape[1:], a.dtype), part)
+                out = _write_rows(out, part, s, e - s)
+            del part, result, st
+            if obs.enabled():
+                obs.inc("fleet.cold.launches")
+                obs.observe("fleet.cold.launch_width", w, obs.COUNT_EDGES)
+                obs.inc("fleet.cold.masked_problems", pad)
+                obs.inc("fleet.cold.rows_valid", sum(sizes[i] for i in idx[s:e]))
+                obs.inc("fleet.cold.rows_padded", w * cap_tiles * m)
+        result, (lpacked, alpha, x_chunks, beta, y_chunks) = out
         state = pred.PosteriorState(
-            lpacked=env["packed"], alpha=env["alpha"],
-            x_chunks=tiling.pad_features(xs, self.tile_size, dtype=self.dtype),
-            n=cap_tiles * self.tile_size, m=self.tile_size, params=bp,
-            beta=env["y"], y_chunks=yc, n_valid=nv, kernel=self.kernel,
+            lpacked=lpacked, alpha=alpha, x_chunks=x_chunks,
+            n=cap_tiles * m, m=m, params=bp, beta=beta, y_chunks=y_chunks,
+            n_valid=nv, kernel=self.kernel,
         )
-        self._buckets[cap_tiles] = _Bucket(tuple(idx), state, key)
-        return state
+        self._buckets[cap_tiles] = _Bucket(tuple(idx), state, self._cache_key())
+        return result, state
 
     # -- prediction ---------------------------------------------------------
 
@@ -1287,6 +1405,24 @@ class GPFleet:
             )
         return x_test
 
+    def _predict_bucket(self, cap, idx, xt, ntv, head: Head):
+        """One bucket's answers to stacked queries: the fused program when
+        its state is stale (exact tier), else the warm tail off the cache."""
+        if self.method == "lowrank":
+            out = lowrank.predict_from_lowrank_state(
+                self._bucket_state(cap, idx), xt, full_cov=head != Head.NONE,
+                n_streams=self.n_streams, backend=self.op_backend, dtype=self.dtype,
+                nt_valid=ntv, batch_dispatch=self.batch_dispatch,
+            )
+            return _variance_from_full(out, head)
+        state = self._warm_state(cap, idx)
+        if state is None:
+            return self._cold_bucket(cap, idx, xt, ntv, head)[0]
+        return pred.predict_from_state_batched(
+            state, xt, head=head, n_streams=self.n_streams, dtype=self.dtype,
+            nt_valid=ntv, mesh=self.mesh,
+        )
+
     def _predict_shared(self, x_test, head: Head):
         """One shared (n̂, D) test block evaluated under every problem."""
         x_test = self._prep_shared(x_test)
@@ -1299,21 +1435,10 @@ class GPFleet:
         elif head == Head.VARIANCE:
             unc = jnp.zeros((b, nh), self.dtype)
         for cap, idx in self.bucket_assignment().items():
-            state = self._bucket_state(cap, idx)
             with _tracer.span("stack"):
                 xt = jnp.broadcast_to(x_test[None], (len(idx),) + x_test.shape)
-            if self.method == "lowrank":
-                out = lowrank.predict_from_lowrank_state(
-                    state, xt, full_cov=head != Head.NONE, n_streams=self.n_streams,
-                    backend=self.op_backend, dtype=self.dtype,
-                    batch_dispatch=self.batch_dispatch,
-                )
-                out = _variance_from_full(out, head)
-            else:
-                out = pred.predict_from_state_batched(
-                    state, xt, head=head,
-                    n_streams=self.n_streams, dtype=self.dtype, mesh=self.mesh,
-                )
+            out = self._predict_bucket(
+                cap, idx, xt, jnp.full((len(idx),), nh, jnp.int32), head)
             gather = jnp.asarray(idx)
             if unc is not None:
                 mean = mean.at[gather].set(out[0])
@@ -1340,8 +1465,9 @@ class GPFleet:
 
     def predict_each(self, x_test_list, *, full_cov: bool = False):
         """Per-problem test sets (list of (n̂_i, D)); ragged n̂_i are padded
-        to each bucket's max and masked with ``nt_valid`` — one batched warm
-        launch per bucket, results sliced back to each problem's own n̂_i.
+        to each bucket's max and masked with ``nt_valid`` — one launch per
+        bucket (or per width-W slice of a cold one), results split back to
+        each problem's own n̂_i.
 
         Returns a length-B list of (n̂_i,) means (or ``(mean, cov)`` tuples
         with cov (n̂_i, n̂_i) when ``full_cov``)."""
@@ -1356,9 +1482,10 @@ class GPFleet:
                 f"got {len(x_test_list)}"
             )
         d = self._xs[0].shape[-1]
+        dt = np.dtype(self.dtype)
         tests = []
-        for i, xt in enumerate(x_test_list):
-            xt = jnp.asarray(xt, self.dtype)
+        for i, xt in enumerate(jax.device_get(list(x_test_list))):
+            xt = np.asarray(xt, dt)
             if xt.ndim == 1:
                 xt = xt[:, None]
             if xt.ndim != 2 or xt.shape[-1] != d:
@@ -1366,6 +1493,7 @@ class GPFleet:
                     f"test set {i} must be (n̂, {d}); got {tuple(xt.shape)}"
                 )
             tests.append(xt)
+        head = Head.COVARIANCE if full_cov else Head.NONE
         out: List[object] = [None] * b
         empty = jnp.zeros((0,), self.dtype)
         empty_cov = jnp.zeros((0, 0), self.dtype)
@@ -1375,35 +1503,24 @@ class GPFleet:
                 for i in idx:
                     out[i] = (empty, empty_cov) if full_cov else empty
                 continue
-            state = self._bucket_state(cap, idx)
             nt_max = max(nts)
             with _tracer.span("stack"):
-                xt = jnp.stack(
-                    [jnp.pad(tests[i], ((0, nt_max - tests[i].shape[0]), (0, 0)))
-                     for i in idx]
-                )
-            if self.method == "lowrank":
-                res = lowrank.predict_from_lowrank_state(
-                    state, xt, full_cov=full_cov, n_streams=self.n_streams,
-                    backend=self.op_backend, dtype=self.dtype,
-                    nt_valid=jnp.asarray(nts, jnp.int32),
-                    batch_dispatch=self.batch_dispatch,
-                )
-            else:
-                res = pred.predict_from_state_batched(
-                    state, xt, head=Head.COVARIANCE if full_cov else Head.NONE,
-                    n_streams=self.n_streams,
-                    dtype=self.dtype, nt_valid=jnp.asarray(nts, jnp.int32),
-                    mesh=self.mesh,
-                )
-            for pos, i in enumerate(idx):
-                if full_cov:
-                    out[i] = (
-                        res[0][pos, : nts[pos]],
-                        res[1][pos, : nts[pos], : nts[pos]],
-                    )
-                else:
-                    out[i] = res[pos, : nts[pos]]
+                xt = np.zeros((len(idx), nt_max, d), dt)
+                for pos, i in enumerate(idx):
+                    xt[pos, : nts[pos]] = tests[i]
+                xt = jnp.asarray(xt)
+            res = self._predict_bucket(
+                cap, idx, xt, jnp.asarray(nts, jnp.int32), head)
+            with _tracer.span("unstack"):
+                means = _rows(res[0] if full_cov else res)
+                covs = _rows(res[1]) if full_cov else None
+                for pos, i in enumerate(idx):
+                    k = nts[pos]
+                    mean = means[pos] if k == nt_max else means[pos][:k]
+                    if not full_cov:
+                        out[i] = mean
+                    else:
+                        out[i] = (mean, covs[pos] if k == nt_max else covs[pos][:k, :k])
         return out
 
     # -- NLML ---------------------------------------------------------------
@@ -1419,7 +1536,7 @@ class GPFleet:
             if self.method == "lowrank":
                 vals = lowrank.nlml_from_lowrank_state(state, dtype=self.dtype)
             else:
-                _, ys, nv = self._stack(idx, cap)
+                _, ys, nv = self._stacked(cap, idx)
                 vals = mll.nlml_from_state(state, ys, dtype=self.dtype, n_valid=nv)
             out = out.at[jnp.asarray(idx)].set(vals.astype(self.dtype))
         return out

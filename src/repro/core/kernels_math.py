@@ -12,12 +12,12 @@ family in the registry keeps the same convention (``lengthscale`` scales
 
 Beyond the paper's SE kernel this module hosts the **kernel registry**
 (DESIGN.md §13): ``Kernel`` subclasses (SE, Matérn 1/2 · 3/2 · 5/2, rational
-quadratic, per-dimension ARD, white noise) and ``Sum`` / ``Product`` /
-``Scaled`` composition.  A kernel is a frozen, hashable dataclass — it joins
-jit/posterior cache keys directly — and its hyperparameters live in a
-separate params *pytree* so the same kernel object serves concrete params
-(Pallas assembly with baked constants) and traced params (differentiable jnp
-assembly under ``grad``).  The contract each kernel implements:
+quadratic, per-dimension ARD SE and Matérn 5/2, white noise) and ``Sum`` /
+``Product`` / ``Scaled`` composition.  A kernel is a frozen, hashable
+dataclass — it joins jit/posterior cache keys directly — and its
+hyperparameters live in a separate params *pytree* so the same kernel object
+serves concrete params (Pallas assembly with baked constants) and traced
+params (differentiable jnp assembly under ``grad``).  The contract each kernel implements:
 
   * ``kfree(params, xa, xb)`` — the noise-free covariance block, pure jnp,
     valid both under tracing and inside a Pallas kernel body with host
@@ -295,41 +295,64 @@ class RationalQuadratic(Kernel):
         return params.vertical * jnp.exp(-params.alpha * jnp.log(base))
 
 
+def _ard_sq_dists(lengthscales, xa, xb):
+    """sum_d (xa_d - xb_d)^2 / l_d: the squared distance of the ARD kernels."""
+    ls = lengthscales
+    if isinstance(ls, tuple):
+        # host-baked Pallas body: a vector constant would be captured by
+        # the kernel jaxpr (pallas_call rejects non-scalar consts), so
+        # ``concrete_params`` hands lengthscales over as a float tuple
+        # and the per-dim scalars inline as literals.
+        if len(ls) == 1:  # shared lengthscale broadcasts over D dims
+            ls = ls * xa.shape[1]
+        d2 = None
+        for d, l in enumerate(ls):
+            diff = xa[:, d : d + 1] - jnp.transpose(xb[:, d : d + 1])
+            term = diff * diff * (1.0 / l)
+            d2 = term if d2 is None else d2 + term
+        return d2
+    ls = jnp.asarray(ls, dtype=xa.dtype)
+    inv = 1.0 / jnp.sqrt(ls)  # scale features so sq_dists stays on the MXU
+    return sq_dists(xa * inv, xb * inv)
+
+
 @dataclasses.dataclass(frozen=True)
-class ARDSquaredExponential(Kernel):
-    """SE with one lengthscale per feature dim: k = v * exp(-0.5 sum d_i^2/l_i)."""
+class _ARDKernel(Kernel):
+    """A stationary family with one lengthscale per feature dimension."""
 
     ndim: int = 1
 
-    name: ClassVar[str] = "se_ard"
-
     def default_params(self) -> ARDKernelParams:
         return ARDKernelParams(lengthscales=jnp.ones((self.ndim,)))
-
-    def kfree(self, params, xa, xb):
-        ls = params.lengthscales
-        if isinstance(ls, tuple):
-            # host-baked Pallas body: a vector constant would be captured by
-            # the kernel jaxpr (pallas_call rejects non-scalar consts), so
-            # ``concrete_params`` hands lengthscales over as a float tuple
-            # and the per-dim scalars inline as literals.
-            if len(ls) == 1:  # shared lengthscale broadcasts over D dims
-                ls = ls * xa.shape[1]
-            d2 = None
-            for d, l in enumerate(ls):
-                diff = xa[:, d : d + 1] - jnp.transpose(xb[:, d : d + 1])
-                term = diff * diff * (1.0 / l)
-                d2 = term if d2 is None else d2 + term
-            return params.vertical * jnp.exp(-0.5 * d2)
-        ls = jnp.asarray(ls, dtype=xa.dtype)
-        inv = 1.0 / jnp.sqrt(ls)  # scale features so sq_dists stays on the MXU
-        return params.vertical * jnp.exp(-0.5 * sq_dists(xa * inv, xb * inv))
 
     def base_ndims(self, params) -> ARDKernelParams:
         return ARDKernelParams(lengthscales=1, vertical=0, noise=0)
 
     def kernel_id(self) -> str:
-        return f"se_ard{self.ndim}"
+        return f"{self.name}{self.ndim}"
+
+
+@dataclasses.dataclass(frozen=True)
+class ARDSquaredExponential(_ARDKernel):
+    """SE with one lengthscale per feature dim: k = v * exp(-0.5 sum d_i^2/l_i)."""
+
+    name: ClassVar[str] = "se_ard"
+
+    def kfree(self, params, xa, xb):
+        return params.vertical * jnp.exp(-0.5 * _ard_sq_dists(params.lengthscales, xa, xb))
+
+
+@dataclasses.dataclass(frozen=True)
+class ARDMatern52(_ARDKernel):
+    """Matérn nu=5/2 with one lengthscale per feature dim (ARBO's residual
+    model): k = v * (1 + sqrt(5) r + 5 r^2 / 3) exp(-sqrt(5) r), with
+    r^2 = sum_d (x_d - x'_d)^2 / l_d."""
+
+    name: ClassVar[str] = "matern52_ard"
+
+    def kfree(self, params, xa, xb):
+        s = math.sqrt(5.0) * _safe_sqrt(_ard_sq_dists(params.lengthscales, xa, xb))
+        return params.vertical * (1.0 + s + s * s / 3.0) * jnp.exp(-s)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -479,6 +502,7 @@ for _cls in (
     Matern52,
     RationalQuadratic,
     ARDSquaredExponential,
+    ARDMatern52,
     White,
 ):
     register_kernel(_cls.name, _cls)

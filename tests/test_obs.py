@@ -234,9 +234,12 @@ def test_fleet_predict_spans_stack_and_bucket(rng, span_log):
     assert names[-1] == "repro.gp.diag"
     buckets = len(fleet.bucket_assignment())
     assert names.count("repro.gp.bucket") == buckets
-    # each cold bucket stacks its problems, then its test block
+    # each cold bucket stacks its problems, then its test block, and runs
+    # the fused program in one launch
     assert names.count("repro.gp.stack") == 2 * buckets
-    assert names.count("repro.predict.nlml_program") == buckets
+    assert names.count("repro.gp.launch") == buckets
+    assert names.count("repro.predict.fused_batched") == buckets
+    assert names.count("repro.predict.nlml_program") == 0
 
 
 # -- factorization health ----------------------------------------------------

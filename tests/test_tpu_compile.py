@@ -116,6 +116,33 @@ def test_fused_variance_predict_compiles_smaller(one_chip):
     assert used[Head.COVARIANCE] - used[Head.VARIANCE] >= (N * N - N) * 4
 
 
+def test_cold_fleet_launch_fits_its_share(one_chip, monkeypatch):
+    # a cold bucket launch at the width GPFleet's rule gives on a v5e: the
+    # ARBO fleet's cap-8 geometry (tile 128, one candidate tile, D = 3,
+    # per-problem ARD params), whose 203 problems do not run in one launch
+    from repro.core import gp
+
+    monkeypatch.setattr(gp, "_device_memory_limit", lambda: V5E_HBM_BYTES)
+    cap, m = 8, 128
+    w = gp._cold_launch_width(cap, 1, m, 4)
+    assert 16 <= w < 203
+    kern = km.ARDMatern52(ndim=3)
+    fn = pred._fused_program_fn(Head.COVARIANCE, None, "jnp", None, None, None,
+                                "flat", None, kern)
+    params = km.ARDKernelParams(_spec(one_chip, (w, 3)), _spec(one_chip, (w,)),
+                                _spec(one_chip, (w,)))
+    compiled = fn.lower(
+        _spec(one_chip, (w, cap, m, 3)), _spec(one_chip, (w, cap, m)),
+        _spec(one_chip, (w, 1, m, 3)), params,
+        _spec(one_chip, (w,), jnp.int32), _spec(one_chip, (w,), jnp.int32),
+    ).compile()
+    ma = compiled.memory_analysis()
+    used = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    # the rule allows a launch 35% of the chip at the worst factor seen; this
+    # geometry compiles under a third
+    assert 0 < used < V5E_HBM_BYTES / 3
+
+
 def test_tiled_nlml_grad_compiles(one_chip):
     grad = jax.jit(
         jax.grad(lambda x, y, p: mll.nlml_tiled(x, y, p, tile_size=TILE), argnums=2)

@@ -100,9 +100,13 @@ def test_fleet_variance_is_covariance_diagonal(rng):
     ys = [rng.standard_normal(n).astype(np.float32) for n in ns]
     fleet = GPFleet(xs, ys, params=PARAMS, tile_size=16)
     xt = rng.standard_normal((11, 2)).astype(np.float32)
-    mean, var = fleet.predict_with_uncertainty(xt)
+    mean, var = fleet.predict_with_uncertainty(xt)  # cold: the fused program
     assert var.shape == (len(ns), 11)
-    _assert_variance_is_diagonal((mean, var), fleet.predict_full_cov(xt))
+    cold_cov = GPFleet(xs, ys, params=PARAMS, tile_size=16).predict_full_cov(xt)
+    _assert_variance_is_diagonal((mean, var), cold_cov)
+    # warm: both heads off the cached bucket states
+    _assert_variance_is_diagonal(fleet.predict_with_uncertainty(xt),
+                                 fleet.predict_full_cov(xt))
     # each problem alone, as a single GP, through the fused variance head
     for i in range(len(ns)):
         mi, vi = GaussianProcess(xs[i], ys[i], params=PARAMS, tile_size=16) \
